@@ -139,7 +139,7 @@ def _growth_rows() -> list:
                 [r._store("svc") for r in reps], futs)
 
     # untimed warmup pass absorbs the one-time jit compiles (prefill hash
-    # shapes, gather_top1, page updater) shared by both arms; the timed
+    # shapes, reuse_top1, page updater) shared by both arms; the timed
     # arms then run interleaved best-of-N so a contention burst hits both
     # sides of the speedup ratio (same idiom as the sweep above)
     _arm("paged", GROWTH_REQS // 4)

@@ -17,8 +17,7 @@ timed reps (must be 0 on the hot path) and sync pages (must be 0 0: mirrors
 are steady-state).
 
 Acceptance (ISSUE 7): >= 3x per-task speedup at batch >= 1024 on a
->= 100k-entry store.  Block sizes honour RESERVOIR_FUSED_BLOCK_Q /
-RESERVOIR_FUSED_BLOCK_C / RESERVOIR_GATHER_MODE.
+>= 100k-entry store.
 
 ``python -m benchmarks.fused_query --smoke`` runs a fast self-check used by
 CI: ~20k-entry store, one 512-task batch, asserts staged/fused result
@@ -27,7 +26,6 @@ dispatch per ``query_batch`` call.
 """
 from __future__ import annotations
 
-import os
 import sys
 import time
 
@@ -72,9 +70,6 @@ def _queries(X: np.ndarray, n: int, seed: int = 1) -> np.ndarray:
 
 def run(n_reps: int = N_REPS) -> list:
     rows: list[Row] = []
-    knobs = (f"block_q={os.environ.get('RESERVOIR_FUSED_BLOCK_Q', '128')};"
-             f"block_c={os.environ.get('RESERVOIR_FUSED_BLOCK_C', '512')};"
-             f"gather={os.environ.get('RESERVOIR_GATHER_MODE', 'take')}")
     for n_store in STORE_SIZES:
         store, X = _make_store(n_store)
         queries = _queries(X, max(BATCH_SIZES))
@@ -117,7 +112,7 @@ def run(n_reps: int = N_REPS) -> list:
                          f"{dispatches};retraces_timed={retraces};"
                          f"sync_pages={store.last_sync_pages} "
                          f"{store.last_table_sync_pages};"
-                         f"cand_width={width};{knobs}"))
+                         f"cand_width={width}"))
     return rows
 
 

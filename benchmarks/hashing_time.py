@@ -6,12 +6,9 @@ Three arms per table count:
 * batched  — ``hash_batch`` amortised over a 256-task batch,
 * fused    — the one-dispatch ``ops.lsh_buckets`` kernel (rotation matmul +
   cross-polytope vertex ids + bucket mixing folded into the kernel
-  epilogue; ISSUE 7 satellite), same 256-task batch.  Tile size honours
-  ``RESERVOIR_HASH_BLOCK_B``.
+  epilogue; ISSUE 7 satellite), same 256-task batch.
 """
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -25,7 +22,6 @@ def run(dim: int = 64) -> list:
     rng = np.random.default_rng(0)
     x1 = rng.standard_normal((1, dim)).astype(np.float32)
     xb = rng.standard_normal((256, dim)).astype(np.float32)
-    block_b = os.environ.get("RESERVOIR_HASH_BLOCK_B", "128")
     for t in (1, 5, 10):
         lsh = get_lsh(LSHParams(dim=dim, num_tables=t, num_probes=8, seed=2))
         nb = lsh.params.num_buckets
@@ -35,6 +31,5 @@ def run(dim: int = 64) -> list:
         rows.append((f"hash_time/tables={t}", us,
                      f"ms_per_task={us / 1e3:.3f};paper_ms={ {1: 0.4, 5: 1.7, 10: 3.3}[t] };"
                      f"batched_us_per_task={us_b / 256:.1f};"
-                     f"fused_kernel_us_per_task={us_k / 256:.1f};"
-                     f"hash_block_b={block_b}"))
+                     f"fused_kernel_us_per_task={us_k / 256:.1f}"))
     return rows

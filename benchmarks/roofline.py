@@ -7,8 +7,7 @@ Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
 
 Also emits analytic rows for the one-dispatch fused reuse query
 (ISSUE 7): per (store, batch) operating point, the hash-matmul compute
-term vs the candidate-gather + top-1 memory term on v5e, with the kernel
-tile knobs echoed so recorded rows are reproducible.
+term vs the candidate-gather + top-1 memory term on v5e.
 """
 from __future__ import annotations
 
@@ -35,8 +34,6 @@ def _fused_query_rows() -> list:
     """
     rows = []
     T, P, K, D = 5, 8, 1, 64
-    bq = os.environ.get("RESERVOIR_FUSED_BLOCK_Q", "128")
-    bc = os.environ.get("RESERVOIR_FUSED_BLOCK_C", "512")
     for n_store, cap in ((100_000, 25), (250_000, 62)):
         for batch in (1024, 10_000):
             w = T * P * cap
@@ -52,7 +49,7 @@ def _fused_query_rows() -> list:
                 dom_s * 1e6,
                 f"hash_s={hash_s:.2e};gather_s={table_s + gather_s:.2e};"
                 f"top1_s={top1_s:.2e};dominant={dominant};"
-                f"cand_width={w};block_q={bq};block_c={bc}"))
+                f"cand_width={w}"))
     return rows
 
 

@@ -38,6 +38,9 @@ BENCHES = (
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     json_path = None
     if "--json" in args:

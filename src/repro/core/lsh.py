@@ -84,6 +84,12 @@ class LSHParams:
         return self.num_buckets
 
 
+# f32 projections at full precision: the TPU's default rounds matmul inputs
+# to bf16, which moves near-tie buckets between the batched, fused and
+# one-row probes of the same embedding.
+_HI = jax.lax.Precision.HIGHEST
+
+
 def _orthogonalize(m: np.ndarray) -> np.ndarray:
     q, r = np.linalg.qr(m)
     return (q * np.sign(np.diag(r))).astype(np.float32)
@@ -107,7 +113,7 @@ def mix_vertex_ids(vids: Array, radix: int, num_buckets: int) -> Array:
 
 def cp_vertex_scores(x: Array, rotations: Array) -> Array:
     """Cross-polytope vertex scores: (B, T, K, 2D); vertex v<D is +e_v."""
-    proj = jnp.einsum("tkde,be->btkd", rotations, x)
+    proj = jnp.einsum("tkde,be->btkd", rotations, x, precision=_HI)
     return jnp.concatenate([proj, -proj], axis=-1)
 
 
@@ -156,7 +162,7 @@ def multiprobe_buckets(
         )
         return buckets.astype(jnp.int32), losses
     # hyperplane: flip bits ranked by |margin|
-    margins = jnp.einsum("tbd,nd->ntb", proj, x)  # (B,T,bits)
+    margins = jnp.einsum("tbd,nd->ntb", proj, x, precision=_HI)  # (B,T,bits)
     bits = (margins > 0).astype(jnp.int32)
     base_bucket = mix_vertex_ids(bits, 2, num_buckets)
     nbits = margins.shape[-1]
@@ -216,7 +222,8 @@ class LSH:
             scores = self._cp_scores(x)  # (B,T,K,2D)
             vids = jnp.argmax(scores, axis=-1).astype(jnp.int32)
             return self._mix(vids)  # (B,T)
-        margins = jnp.einsum("tbd,nd->ntb", self.planes, x)  # (B,T,bits)
+        margins = jnp.einsum("tbd,nd->ntb", self.planes, x,
+                             precision=_HI)  # (B,T,bits)
         bits = (margins > 0).astype(jnp.int32)
         return self._mix(bits)
 

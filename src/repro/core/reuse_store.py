@@ -11,8 +11,8 @@ fixed-capacity contiguous bucket array — ``(T, num_buckets, bucket_cap)``
 int32 slot ids plus ``(T, num_buckets)`` fill counts — instead of a Python
 dict of lists.  Probe -> candidate-gather is then pure vectorized indexing,
 and the batched ``query_batch`` path services a whole batch of tasks with one
-``probe_batch`` dispatch plus one fused gather/score kernel call
-(``kernels.sim_topk.gather_top1``).  Buckets that exceed ``bucket_cap``
+``probe_batch`` dispatch plus one gather/score kernel call
+(``kernels.sim_topk.reuse_top1``).  Buckets that exceed ``bucket_cap``
 overwrite their oldest slot ring-buffer style (the displaced entry stays
 reachable through its other tables; ``overflows`` counts occurrences).
 

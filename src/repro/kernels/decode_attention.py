@@ -72,7 +72,7 @@ def decode_attention(
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]

@@ -86,7 +86,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]

@@ -26,8 +26,7 @@ Compile-cache design: the probe math is the *module-level*
 arguments, so one compilation serves every store whose static config
 (family, probes, table/page shapes, blocks) matches — LSH seeds and store
 contents never retrace.  Callers pad B to a multiple of 8 (ops.py); the
-candidate width T*P*cap is static per store config and padded to a multiple
-of 64 here.
+candidate width T*P*cap is static per store config.
 """
 from __future__ import annotations
 
@@ -45,12 +44,10 @@ FUSED_TRACE_COUNT = 0
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "family", "num_probes", "gather_mode", "block_q", "block_c", "interpret",
-    "with_counts"))
+    "family", "num_probes", "block_q", "block_c", "interpret", "with_counts"))
 def fused_query(embs: jax.Array, proj: jax.Array, slots_flat: jax.Array,
                 pages: jax.Array, *, family: str, num_probes: int,
-                gather_mode: str = "take", block_q: int = 128,
-                block_c: int = 512, interpret: bool = True,
+                block_q: int = 8, block_c: int = 512, interpret: bool,
                 with_counts: bool = True):
     """hash -> probe -> gather -> top-1 in one dispatch.
 
@@ -61,7 +58,7 @@ def fused_query(embs: jax.Array, proj: jax.Array, slots_flat: jax.Array,
 
     Returns (best (B,) f32, idx (B,) int32 store row ids with -1 = no
     candidate, extra): extra is the (B,) int32 exact unique-candidate
-    counts when ``with_counts``, else the raw padded (B, Wp) candidate-id
+    counts when ``with_counts``, else the raw (B, T*P*cap) candidate-id
     matrix (for host-side counting — see module docstring).
     """
     global FUSED_TRACE_COUNT
@@ -80,13 +77,8 @@ def fused_query(embs: jax.Array, proj: jax.Array, slots_flat: jax.Array,
     slots = slots_flat.reshape(t, nb, cap)
     t_idx = jnp.arange(t, dtype=jnp.int32)[None, :, None]
     ids = slots[t_idx, buckets].reshape(b, -1)          # (B, T*P*cap)
-    w = ids.shape[1]
-    wp = max(-(-w // 64) * 64, 64)
-    if wp != w:
-        ids = jnp.pad(ids, ((0, 0), (0, wp - w)), constant_values=-1)
-    val, idx = reuse_top1(
-        embs, pages, ids, block_q=block_q, block_c=block_c,
-        interpret=interpret, gather_mode=gather_mode)
+    val, idx = reuse_top1(embs, pages, ids, block_q=block_q, block_c=block_c,
+                          interpret=interpret)
     if not with_counts:
         return val, idx, ids
     # exact unique-candidate counts: -1 pads sort to the front, a run-length

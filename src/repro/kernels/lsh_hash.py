@@ -20,6 +20,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _vertex_ids(proj):
+    """Closest cross-polytope vertex of each row: argmax over [proj, -proj]
+    (vertex v < D is +e_v), with core.lsh's first-index tie-break."""
+    neg = jnp.max(-proj, axis=-1) > jnp.max(proj, axis=-1)
+    return jnp.where(neg, jnp.argmax(-proj, axis=-1) + proj.shape[-1],
+                     jnp.argmax(proj, axis=-1)).astype(jnp.int32)
+
+
 def _lsh_hash_kernel(x_ref, rot_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)           # (bB, D)
     rot = rot_ref[0, 0].astype(jnp.float32)      # (D, D)
@@ -27,22 +35,16 @@ def _lsh_hash_kernel(x_ref, rot_ref, out_ref):
     proj = jax.lax.dot_general(
         x, rot, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)      # (bB, D) on the MXU
-    absp = jnp.abs(proj)
-    vid = jnp.argmax(absp, axis=-1)              # (bB,)
-    mx = jnp.max(absp, axis=-1)
-    sign_neg = jnp.take_along_axis(proj, vid[:, None], axis=-1)[:, 0] < 0
-    d = proj.shape[-1]
-    out = jnp.where(sign_neg, vid + d, vid).astype(jnp.int32)
-    del mx
-    out_ref[...] = out[:, None, None]
+    out_ref[...] = _vertex_ids(proj)[:, None]
 
 
 def _lsh_hash_mix_kernel(x_ref, rot_ref, out_ref, *, radix: int, num_buckets: int):
     """Hash + modular-mixing epilogue: out revisited across the K grid steps.
 
     The K axis is innermost (sequential on TPU), and the out block's index map
-    ignores it, so the (bB, 1) bucket accumulator stays resident in VMEM while
-    each rotation folds its vertex id in:  acc = (acc * radix + vid) % NB.
+    ignores it, so the (bB, 1) bucket accumulator of table t stays resident in
+    VMEM while each rotation folds its vertex id in:
+    acc = (acc * radix + vid) % NB.
     This removes the K host-side mixing steps `ops.lsh_buckets` used to run.
     """
     k = pl.program_id(2)
@@ -51,11 +53,7 @@ def _lsh_hash_mix_kernel(x_ref, rot_ref, out_ref, *, radix: int, num_buckets: in
     proj = jax.lax.dot_general(
         x, rot, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)      # (bB, D)
-    absp = jnp.abs(proj)
-    vid = jnp.argmax(absp, axis=-1)              # (bB,)
-    sign_neg = jnp.take_along_axis(proj, vid[:, None], axis=-1)[:, 0] < 0
-    d = proj.shape[-1]
-    vid = jnp.where(sign_neg, vid + d, vid).astype(jnp.int32)
+    vid = _vertex_ids(proj)
 
     @pl.when(k == 0)
     def _init():
@@ -68,7 +66,7 @@ def _lsh_hash_mix_kernel(x_ref, rot_ref, out_ref, *, radix: int, num_buckets: in
 @functools.partial(
     jax.jit, static_argnames=("num_buckets", "block_b", "interpret"))
 def lsh_hash_mix(x: jax.Array, rotations: jax.Array, *, num_buckets: int,
-                 block_b: int = 128, interpret: bool = True) -> jax.Array:
+                 block_b: int = 128, interpret: bool) -> jax.Array:
     """x: (B, D); rotations: (T, K, D, D) -> (B, T) int32 mixed bucket ids.
 
     One dispatch for the whole cross-polytope hash including the bucket
@@ -87,15 +85,17 @@ def lsh_hash_mix(x: jax.Array, rotations: jax.Array, *, num_buckets: int,
             pl.BlockSpec((bB, D), lambda b, t, k: (b, 0)),
             pl.BlockSpec((1, 1, D, D), lambda b, t, k: (t, k, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bB, 1), lambda b, t, k: (b, t)),
-        out_shape=jax.ShapeDtypeStruct((B, T), jnp.int32),
+        # table-major (T, B, 1) output: a (bB, 1) block of a (B, T) array
+        # breaks the TPU's (8, 128) block rule for T > 1
+        out_specs=pl.BlockSpec((None, bB, 1), lambda b, t, k: (t, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, B, 1), jnp.int32),
         interpret=interpret,
-    )(x, rotations)
+    )(x, rotations)[..., 0].T
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def lsh_hash(x: jax.Array, rotations: jax.Array, *, block_b: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool) -> jax.Array:
     """x: (B, D) f32/bf16; rotations: (T, K, D, D) -> (B, T, K) int32 ids."""
     B, D = x.shape
     T, K = rotations.shape[:2]
@@ -108,7 +108,8 @@ def lsh_hash(x: jax.Array, rotations: jax.Array, *, block_b: int = 128,
             pl.BlockSpec((bB, D), lambda b, t, k: (b, 0)),
             pl.BlockSpec((1, 1, D, D), lambda b, t, k: (t, k, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bB, 1, 1), lambda b, t, k: (b, t, k)),
-        out_shape=jax.ShapeDtypeStruct((B, T, K), jnp.int32),
+        out_specs=pl.BlockSpec((None, None, bB, 1),
+                               lambda b, t, k: (t, k, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, K, B, 1), jnp.int32),
         interpret=interpret,
-    )(x, rotations)
+    )(x, rotations)[..., 0].transpose(2, 0, 1)

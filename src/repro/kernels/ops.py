@@ -7,8 +7,6 @@ correctness), and dtype plumbing.  Every op has a pure-jnp oracle in
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -21,7 +19,6 @@ from .flash_attention import flash_attention as _flash_kernel
 from .fused_query import fused_query as _fused_query
 from .lsh_hash import lsh_hash as _lsh_kernel
 from .lsh_hash import lsh_hash_mix as _lsh_mix_kernel
-from .sim_topk import gather_top1 as _gather_kernel
 from .sim_topk import reuse_top1 as _reuse_kernel
 from .sim_topk import sim_top1 as _sim_kernel
 
@@ -32,17 +29,22 @@ from .sim_topk import sim_top1 as _sim_kernel
 FUSED_DISPATCH_COUNT = 0
 
 
-def _env_int(name: str, default: int) -> int:
-    v = os.environ.get(name)
-    return int(v) if v else default
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _interpret() -> bool:
-    return not _on_tpu()
+    """Interpret the kernels on the CPU (tests), compile them on the TPU."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels target the TPU (interpreted on the CPU); "
+        f"backend {backend!r} has no path")
+
+
+def _top1_blocks() -> dict:
+    """reuse_top1 tiles: the kernel's v5e-sized defaults on the chip; under
+    interpret mode larger tiles cut the interpreter's per-step overhead."""
+    return {"block_q": 128, "block_c": 512} if _interpret() else {}
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> Tuple[jax.Array, int]:
@@ -69,13 +71,10 @@ def lsh_buckets(x: jax.Array, rotations: jax.Array, num_buckets: int) -> jax.Arr
     The K modular-mixing steps run inside the kernel epilogue (the bucket
     accumulator block stays VMEM-resident across the sequential K grid axis)
     instead of as host-side jnp ops after the hash kernel returned.
-    ``RESERVOIR_HASH_BLOCK_B`` tunes the batch tile.
     """
     xp, b = _pad_to(x, 0, 8)
-    out = _lsh_mix_kernel(
-        xp, rotations, num_buckets=num_buckets,
-        block_b=_env_int("RESERVOIR_HASH_BLOCK_B", 128),
-        interpret=_interpret())
+    out = _lsh_mix_kernel(xp, rotations, num_buckets=num_buckets,
+                          interpret=_interpret())
     return out[:b]
 
 
@@ -96,50 +95,33 @@ def nearest_neighbor(q: jax.Array, store: jax.Array,
     return val[:nq], idx[:nq]
 
 
-def _pad_ids(ids: jax.Array, axis: int, mult: int) -> jax.Array:
-    """Pad a candidate-id array with -1 (invalid) up to a multiple of mult."""
-    n = ids.shape[axis]
-    target = max(-(-n // mult) * mult, mult)
-    if target == n:
-        return ids
-    pad = [(0, 0)] * ids.ndim
-    pad[axis] = (0, target - n)
-    return jnp.pad(ids, pad, constant_values=-1)
-
-
 def gathered_top1(q: jax.Array, store: jax.Array, cand_ids: jax.Array):
-    """Fused multi-probe gather + masked cosine top-1 (batched reuse query).
+    """Candidate gather + masked cosine top-1 (the host-staged reuse query).
 
     q: (Q, D) unit rows; store: (N, D) unit rows, or the reuse store's paged
     (num_pages, page_size, D) device buffer — slot ids then address row
-    ``page * page_size + offset`` and the kernel gathers through the
-    (page, offset) decomposition without flattening the buffer.  cand_ids:
-    (Q, C) int32 store row ids, -1 = unused slot.  Returns (best (Q,) f32,
-    idx (Q,) int32) where idx is a store row id and -1/-inf mark queries
-    without candidates.
-
-    Candidate width is padded to a multiple of 64 (queries to 8) so repeated
-    calls with drifting candidate counts reuse a small set of compilations.
-    A paged store is passed through unpadded: its row count is
-    num_pages * page_size, already a hardware-friendly multiple — the reuse
-    store rounds page_size up to a multiple of 8 at allocation, so pages
-    always tile cleanly on TPU and no flatten-copy valve is needed here.
+    ``page * page_size + offset``.  cand_ids: (Q, C) int32 store row ids,
+    -1 = unused slot.  Returns (best (Q,) f32, idx (Q,) int32) where idx is a
+    store row id and -1/-inf mark queries without candidates; equal
+    similarities resolve to the lowest id, which for the staged path's sorted
+    unique candidates is the first one.
     """
     q = jnp.atleast_2d(q)
     nq = q.shape[0]
-    paged = store.ndim == 3
-    n_rows = (store.shape[0] * store.shape[1]) if paged else store.shape[0]
+    n_rows = int(np.prod(store.shape[:-1]))
     if n_rows == 0 or cand_ids.shape[1] == 0:
         return (jnp.full((nq,), -jnp.inf, jnp.float32),
                 jnp.full((nq,), -1, jnp.int32))
+    # queries pad to a multiple of 8 and candidates to one of 128 (the
+    # kernel's lane tile), so drifting batch sizes and candidate counts
+    # share a few compilations
     qp, _ = _pad_to(q, 0, 8)
-    ids = _pad_ids(jnp.asarray(cand_ids, jnp.int32), 1, 64)
-    ids = _pad_ids(ids, 0, 8)
-    sp = store if paged else _pad_to(store, 0, 8)[0]
-    # Small blocks keep the gathered (bQ, bC, D) tile cache-resident on CPU;
-    # the TPU path prefers the kernel's larger MXU-aligned defaults.
-    blocks = {"block_q": 128, "block_c": 512} if _interpret() else {}
-    val, idx = _gather_kernel(qp, sp, ids, interpret=_interpret(), **blocks)
+    c = cand_ids.shape[1]
+    ids = jnp.pad(jnp.asarray(cand_ids, jnp.int32),
+                  ((0, qp.shape[0] - nq), (0, -(-c // 128) * 128 - c)),
+                  constant_values=-1)
+    val, idx = _reuse_kernel(qp, store, ids, interpret=_interpret(),
+                             **_top1_blocks())
     return val[:nq], idx[:nq]
 
 
@@ -153,8 +135,6 @@ def unique_counts(cand: "np.ndarray") -> "np.ndarray":
     than XLA:CPU, so the interpret-mode fused path counts here instead of
     in-jit (TPU keeps the device-side epilogue).
     """
-    import numpy as np
-
     srt = np.sort(cand, axis=1)
     first = np.concatenate(
         [np.ones((srt.shape[0], 1), bool), srt[:, 1:] != srt[:, :-1]], axis=1)
@@ -162,10 +142,7 @@ def unique_counts(cand: "np.ndarray") -> "np.ndarray":
 
 
 def reuse_query_top1(embs, lsh, slots_dev: jax.Array, pages_dev: jax.Array,
-                     *, block_q: Optional[int] = None,
-                     block_c: Optional[int] = None,
-                     gather_mode: Optional[str] = None,
-                     need_counts: bool = True):
+                     *, need_counts: bool = True):
     """One-dispatch batched reuse query over the device-resident store.
 
     embs: (B, D) unit rows (host or device); lsh: the store's ``core.lsh.LSH``
@@ -182,16 +159,9 @@ def reuse_query_top1(embs, lsh, slots_dev: jax.Array, pages_dev: jax.Array,
     they are counted host-side (``unique_counts``) from the returned raw
     candidate matrix — still a single device dispatch either way.
 
-    Knobs: ``RESERVOIR_FUSED_BLOCK_Q`` / ``RESERVOIR_FUSED_BLOCK_C`` tune the
-    kernel tiles, ``RESERVOIR_GATHER_MODE=onehot`` selects the one-hot matmul
-    candidate gather for TPU targets where the Mosaic dynamic row gather does
-    not lower (small stores only — it is O(C * N * D) MXU work).
-
     B is padded to a multiple of 8; everything else in the signature is
     static per store config, so steady-state traffic reuses one compilation.
     """
-    import numpy as np
-
     global FUSED_DISPATCH_COUNT
     p = lsh.params
     proj = lsh.rotations if p.family == "cross_polytope" else lsh.planes
@@ -202,10 +172,7 @@ def reuse_query_top1(embs, lsh, slots_dev: jax.Array, pages_dev: jax.Array,
     val, idx, extra = _fused_query(
         xp, proj, slots_dev, pages_dev,
         family=p.family, num_probes=p.num_probes,
-        gather_mode=gather_mode or os.environ.get("RESERVOIR_GATHER_MODE", "take"),
-        block_q=block_q or _env_int("RESERVOIR_FUSED_BLOCK_Q", 128),
-        block_c=block_c or _env_int("RESERVOIR_FUSED_BLOCK_C", 512),
-        interpret=interp, with_counts=not interp)
+        interpret=interp, with_counts=not interp, **_top1_blocks())
     FUSED_DISPATCH_COUNT += 1
     if not need_counts:
         counts = None

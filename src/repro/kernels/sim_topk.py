@@ -8,31 +8,24 @@ similarity is a plain matmul.  Two kernels:
   Grid: (Q / bQ, N / bN) with N innermost — TPU grids execute sequentially,
   so a VMEM scratch carries the running (best value, best index) across N
   tiles and the result is written once at the last tile.  This streams an
-  arbitrarily large store through VMEM with O(bQ) state.
+  arbitrarily large store through VMEM with O(bQ) state.  No served path
+  calls it, and the v5e compiler refuses its load of the ``pl.ANY`` operand.
 
-* ``gather_top1`` — the multi-probe batch path (DESIGN.md §Array-native
-  store).  Each query carries its own LSH candidate list (store row ids,
-  ``-1`` padded); the kernel gathers candidate embeddings by slot id and
-  computes the masked cosine top-1 in the same pass.  Grid: (Q / bQ, C / bC)
-  with candidates innermost and the same running-best scratch scheme, so
-  work is O(B * C * D) — the candidate set, not the store size.  The store
-  operand is either a flat ``(N, D)`` matrix or the reuse store's *paged*
-  device buffer ``(num_pages, page_size, D)``; in the paged case the kernel
-  decomposes each slot id as ``(id // page_size, id % page_size)`` and
-  gathers through (page, offset), so the caller never has to flatten (=
-  copy) the paged residency.  The gather lowers to a Mosaic dynamic row
-  gather on TPU; on CPU the kernels run in interpret mode (see ops.py).
+* ``reuse_top1`` — the store search's top-1 stage, shared by the
+  one-dispatch query path (DESIGN.md §One-dispatch query path, candidates
+  raw from the device slot tables) and the host-staged path (sorted unique
+  candidates, ``ops.gathered_top1``).  Each query carries its own candidate
+  list of store row ids (``-1`` = empty slot); the store is a flat
+  ``(N, D)`` matrix or the reuse store's paged ``(num_pages, page_size, D)``
+  device buffer.  XLA gathers the candidate rows, and the kernel streams
+  (bQ, bC, D) tiles with candidates innermost, keeping a *lexicographic*
+  (max similarity, then min store row id) running best: the lowest-id rule
+  reproduces the host path's argmax-over-sorted-unique semantics without
+  sorting.  Work is O(B * C * D) — the candidate set, not the store size.
+  The data-dependent row gather stays in XLA because Mosaic reads an HBM
+  (``pl.ANY``) operand only through DMA.
 
-* ``reuse_top1`` — the one-dispatch query path's top-1 stage (DESIGN.md
-  §One-dispatch query path).  Same gather + masked cosine scheme as
-  ``gather_top1`` but with an explicit *lexicographic* (max similarity,
-  then min store row id) running best: candidate lists arrive straight
-  from the device slot tables — unsorted, with duplicates — and the
-  lowest-id-wins rule reproduces the host path's argmax-over-sorted-unique
-  semantics without sorting.  ``gather_mode`` selects the Mosaic dynamic
-  row gather (``"take"``) or a one-hot matmul fallback (``"onehot"``) for
-  TPU generations where the dynamic gather does not lower; the fallback is
-  O(C * N * D) MXU work and only sensible for small stores.
+On CPU the kernels run in interpret mode (see ops.py).
 """
 from __future__ import annotations
 
@@ -78,7 +71,7 @@ def _sim_top1_kernel(q_ref, s_ref, nvalid_ref, val_ref, idx_ref,
 @functools.partial(jax.jit, static_argnames=("block_q", "block_n", "interpret"))
 def sim_top1(q: jax.Array, store: jax.Array, n_valid: jax.Array | None = None,
              *, block_q: int = 128, block_n: int = 512,
-             interpret: bool = True):
+             interpret: bool):
     """q: (Q, D); store: (N, D), rows L2-normalised. -> (best (Q,), idx (Q,)).
 
     ``n_valid`` masks the tail of a pre-allocated (ring-buffer) store.
@@ -113,204 +106,109 @@ def sim_top1(q: jax.Array, store: jax.Array, n_valid: jax.Array | None = None,
     return val, idx
 
 
-def _gather_top1_kernel(q_ref, ids_ref, store_ref, val_ref, idx_ref,
-                        best_val, best_idx):
+# Upper bound on the gathered candidate rows one kernel launch streams: the
+# XLA gather materializes (rows, C, D) f32 in HBM ahead of the kernel, so a
+# batch whose candidate tensor would exceed this is split into query chunks
+# (1024 queries x 20480 candidates x 64 dims is 5.4 GB unchunked).
+GATHER_BUDGET_BYTES = 128 << 20
+
+
+def _reuse_top1_kernel(q_ref, ids_ref, cand_ref, val_ref, idx_ref):
     j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        best_val[...] = jnp.full_like(best_val, -jnp.inf)
-        best_idx[...] = jnp.full_like(best_idx, -1)
-
-    q = q_ref[...].astype(jnp.float32)                 # (bQ, D)
-    ids = ids_ref[...]                                 # (bQ, bC) int32, -1 pad
-    valid = ids >= 0
-    safe = jnp.where(valid, ids, 0)
-    store = store_ref[...]                             # (N, D) | (P, S, D)
-    flat = safe.reshape(-1)
-    if store.ndim == 3:
-        # paged store: slot id -> (page, offset) row gather
-        page_size = store.shape[1]
-        pg = jnp.clip(flat // page_size, 0, store.shape[0] - 1)
-        cand = store[pg, flat % page_size]
-    else:
-        cand = jnp.take(store, flat, axis=0, mode="clip")
-    cand = cand.reshape(safe.shape + (q.shape[-1],)).astype(jnp.float32)
-    scores = jnp.einsum("qd,qcd->qc", q, cand)         # (bQ, bC) on the VPU
-    scores = jnp.where(valid, scores, -jnp.inf)
-    tile_val = jnp.max(scores, axis=-1)                # (bQ,)
-    pos = jnp.argmax(scores, axis=-1)
-    tile_idx = jnp.take_along_axis(safe, pos[:, None], axis=-1)[:, 0]
-    tile_idx = jnp.where(tile_val > -jnp.inf, tile_idx, -1).astype(jnp.int32)
-    better = tile_val > best_val[...]
-    best_val[...] = jnp.where(better, tile_val, best_val[...])
-    best_idx[...] = jnp.where(better, tile_idx, best_idx[...])
-
-    @pl.when(j == nj - 1)
-    def _done():
-        val_ref[...] = best_val[...]
-        idx_ref[...] = best_idx[...]
-
-
-@functools.partial(jax.jit, static_argnames=("block_q", "block_c", "interpret"))
-def gather_top1(q: jax.Array, store: jax.Array, cand_ids: jax.Array,
-                *, block_q: int = 128, block_c: int = 1024,
-                interpret: bool = True):
-    """Fused candidate-gather + masked cosine top-1.
-
-    q: (Q, D) unit rows; store: (N, D) unit rows or a paged
-    (num_pages, page_size, D) device buffer; cand_ids: (Q, C) int32 store
-    row ids with -1 marking unused slots (paged stores address row
-    ``page * page_size + offset``).  Returns (best (Q,), idx (Q,)) where
-    idx is a *store row id* (-1 and best=-inf when a query has no candidates).
-    """
-    Q, D = q.shape
-    C = cand_ids.shape[1]
-    bQ, bC = min(block_q, Q), min(block_c, C)
-    grid = (pl.cdiv(Q, bQ), pl.cdiv(C, bC))
-    val, idx = pl.pallas_call(
-        _gather_top1_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bQ, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((bQ, bC), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((bQ,), lambda i, j: (i,)),
-            pl.BlockSpec((bQ,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q,), jnp.float32),
-            jax.ShapeDtypeStruct((Q,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bQ,), jnp.float32),
-            pltpu.VMEM((bQ,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q, cand_ids.astype(jnp.int32), store)
-    return val, idx
-
-
-def _gather_rows(store, flat_ids, *, gather_mode: str):
-    """Gather store rows by flat slot id -> (len(flat_ids), D) f32.
-
-    ``take``: Mosaic dynamic row gather (paged stores go through the
-    (page, offset) decomposition).  ``onehot``: one-hot matmul fallback for
-    targets where the dynamic gather does not lower — builds a
-    (len(ids), N) selector and hits the MXU; fine for small stores only.
-    """
-    if gather_mode == "onehot":
-        flat_store = store.reshape(-1, store.shape[-1]) if store.ndim == 3 else store
-        n = flat_store.shape[0]
-        sel = (flat_ids[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (1, n), 1)).astype(jnp.float32)
-        return jax.lax.dot_general(
-            sel, flat_store.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    if store.ndim == 3:
-        page_size = store.shape[1]
-        pg = jnp.clip(flat_ids // page_size, 0, store.shape[0] - 1)
-        return store[pg, flat_ids % page_size].astype(jnp.float32)
-    return jnp.take(store, flat_ids, axis=0, mode="clip").astype(jnp.float32)
-
-
-def _reuse_top1_kernel(q_ref, ids_ref, store_ref, val_ref, idx_ref,
-                       best_val, best_idx, *, gather_mode: str,
-                       block_q: int, block_c: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
     imax = jnp.iinfo(jnp.int32).max
 
+    # the (bQ, 1) outputs stay resident across the sequential candidate axis
+    # and carry the running best
     @pl.when(j == 0)
     def _init():
-        best_val[...] = jnp.full_like(best_val, -jnp.inf)
-        best_idx[...] = jnp.full_like(best_idx, imax)
+        val_ref[...] = jnp.full_like(val_ref, -jnp.inf)
+        idx_ref[...] = jnp.full_like(idx_ref, imax)
 
-    # q and ids live in ANY memory space and are tile-loaded here by
-    # program id: blocked operands are carried through the grid loop with a
-    # full-array copy per step, which turns O(B * C) inputs quadratic in
-    # the batch — the explicit load keeps each step O(block).
-    q = pl.load(q_ref, (pl.dslice(i * block_q, block_q),
-                        slice(None))).astype(jnp.float32)   # (bQ, D)
-    ids = pl.load(ids_ref, (pl.dslice(i * block_q, block_q),
-                            pl.dslice(j * block_c, block_c)))  # (bQ, bC)
+    q = q_ref[...].astype(jnp.float32)                 # (bQ, D)
+    ids = ids_ref[...]                                 # (bQ, bC)
+    cand = cand_ref[...].astype(jnp.float32)           # (bQ, bC, D)
+    # exact f32 multiply-add on the VPU: no MXU pass rounding, so the scores
+    # agree with the host's numpy cosine to f32 accuracy
+    scores = jnp.sum(cand * q[:, None, :], axis=-1)    # (bQ, bC)
     valid = ids >= 0
-    safe = jnp.where(valid, ids, 0)
-    store = store_ref[...]                             # (N, D) | (P, S, D)
-    cand = _gather_rows(store, safe.reshape(-1), gather_mode=gather_mode)
-    cand = cand.reshape(safe.shape + (q.shape[-1],))
-    scores = jnp.einsum("qd,qcd->qc", q, cand)         # (bQ, bC)
     scores = jnp.where(valid, scores, -jnp.inf)
-    tile_val = jnp.max(scores, axis=-1)                # (bQ,)
+    tile_val = jnp.max(scores, axis=-1, keepdims=True)  # (bQ, 1)
     # lexicographic running best: duplicate ids score bit-equal, so taking
     # the *minimum* id among this tile's maxima reproduces the host path's
     # argmax-over-sorted-unique tie-break without sorting candidates.
-    elig = valid & (scores >= tile_val[:, None])
-    tile_idx = jnp.min(jnp.where(elig, ids, imax), axis=-1)
-    bv, bi = best_val[...], best_idx[...]
+    elig = valid & (scores >= tile_val)
+    tile_idx = jnp.min(jnp.where(elig, ids, imax), axis=-1, keepdims=True)
+    bv, bi = val_ref[...], idx_ref[...]
     better = (tile_val > bv) | ((tile_val == bv) & (tile_idx < bi))
-    best_val[...] = jnp.where(better, tile_val, bv)
-    best_idx[...] = jnp.where(better, tile_idx, bi)
+    val_ref[...] = jnp.where(better, tile_val, bv)
+    idx_ref[...] = jnp.where(better, tile_idx, bi)
 
-    @pl.when(j == nj - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _done():
-        val_ref[...] = best_val[...]
-        idx_ref[...] = jnp.where(
-            best_val[...] > -jnp.inf, best_idx[...], -1).astype(jnp.int32)
+        idx_ref[...] = jnp.where(val_ref[...] > -jnp.inf, idx_ref[...], -1)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "block_q", "block_c", "interpret", "gather_mode"))
+def _round_up(n: int, m: int) -> int:
+    return max(-(-n // m) * m, m)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_c", "interpret"))
 def reuse_top1(q: jax.Array, store: jax.Array, cand_ids: jax.Array,
-               *, block_q: int = 128, block_c: int = 512,
-               interpret: bool = True, gather_mode: str = "take"):
-    """Masked cosine top-1 with lowest-id tie-break over raw table candidates.
+               *, block_q: int = 8, block_c: int = 512, interpret: bool):
+    """Masked cosine top-1 with lowest-id tie-break over candidate row ids.
 
     q: (Q, D) unit rows; store: flat (N, D) or paged
-    (num_pages, page_size, D) device buffer; cand_ids: (Q, C) int32 store row
-    ids straight from the slot tables — unsorted, duplicated, -1 = empty
-    slot.  Returns (best (Q,), idx (Q,)): idx is the lowest store row id
-    among the maximum-similarity candidates (-1 / -inf when a query has no
-    valid candidate), matching the host path's sorted-unique argmax.
+    (num_pages, page_size, D) device buffer (slot id = page * page_size +
+    offset); cand_ids: (Q, C) int32 store row ids in any order, duplicates
+    allowed, -1 = empty slot.  Returns (best (Q,), idx (Q,)): idx is the
+    lowest store row id among the maximum-similarity candidates (-1 / -inf
+    when a query has no valid candidate), matching the host path's
+    sorted-unique argmax.
+
+    The rows are gathered by XLA and the kernel streams blocked
+    (bQ, bC, D) candidate tiles; the default blocks fit the v5e's scoped
+    VMEM.  Queries are processed in chunks of at most
+    ``GATHER_BUDGET_BYTES`` of gathered candidates.
     """
     Q, D = q.shape
     C = cand_ids.shape[1]
-    bQ, bC = min(block_q, Q), min(block_c, C)
-    # q/ids are manually tile-loaded from ANY memory space (see kernel), so
-    # pad them to block multiples up front; padded rows have no valid
-    # candidate and fall out as (-inf, -1), sliced off below.
-    Qp, Cp = -(-Q // bQ) * bQ, -(-C // bC) * bC
-    if Qp != Q:
-        q = jnp.pad(q, ((0, Qp - Q), (0, 0)))
-    ids = cand_ids.astype(jnp.int32)
-    if Qp != Q or Cp != C:
-        ids = jnp.pad(ids, ((0, Qp - Q), (0, Cp - C)), constant_values=-1)
-    grid = (Qp // bQ, Cp // bC)
-    val, idx = pl.pallas_call(
-        functools.partial(_reuse_top1_kernel, gather_mode=gather_mode,
-                          block_q=bQ, block_c=bC),
-        grid=grid,
+    bQ = min(block_q, _round_up(Q, 8))
+    bC = min(block_c, _round_up(C, 128))
+    Cp = _round_up(C, bC)
+    rows = max(bQ, min(_round_up(Q, bQ), GATHER_BUDGET_BYTES // (Cp * D * 4))
+               // bQ * bQ)
+    n_chunks = -(-Q // rows)
+    Qp = n_chunks * rows
+    q = jnp.pad(q, ((0, Qp - Q), (0, 0)))
+    ids = jnp.pad(cand_ids.astype(jnp.int32), ((0, Qp - Q), (0, Cp - C)),
+                  constant_values=-1)
+    flat = store.reshape(-1, D)
+    top1 = pl.pallas_call(
+        _reuse_top1_kernel,
+        grid=(rows // bQ, Cp // bC),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((bQ, D), lambda i, j: (i, 0)),
+            pl.BlockSpec((bQ, bC), lambda i, j: (i, j)),
+            pl.BlockSpec((bQ, bC, D), lambda i, j: (i, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bQ,), lambda i, j: (i,)),
-            pl.BlockSpec((bQ,), lambda i, j: (i,)),
+            pl.BlockSpec((bQ, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bQ, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Qp,), jnp.float32),
-            jax.ShapeDtypeStruct((Qp,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bQ,), jnp.float32),
-            pltpu.VMEM((bQ,), jnp.int32),
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, ids, store)
-    return val[:Q], idx[:Q]
+    )
+
+    def chunk(args):
+        qc, idc = args
+        cand = jnp.take(flat, jnp.maximum(idc, 0), axis=0, mode="clip")
+        return top1(qc, idc, cand)
+
+    val, idx = jax.lax.map(chunk, (q.reshape(n_chunks, rows, D),
+                                   ids.reshape(n_chunks, rows, Cp)))
+    return val.reshape(-1)[:Q], idx.reshape(-1)[:Q]

@@ -1,19 +1,19 @@
 """Per-kernel validation: sweep shapes/dtypes, assert_allclose vs ref.py.
 
-Kernels run in interpret mode on CPU (the TPU target is structural:
-pallas_call + BlockSpec); the oracles are pure jnp.
+Kernels run in interpret mode on CPU (tests/test_tpu_compile.py compiles
+the served ones for the v5e); the oracles are pure jnp.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, sim_topk
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_query import fused_query
 from repro.kernels.lsh_hash import lsh_hash, lsh_hash_mix
-from repro.kernels.sim_topk import gather_top1, reuse_top1, sim_top1
+from repro.kernels.sim_topk import reuse_top1, sim_top1
 
 RNG = np.random.default_rng(42)
 
@@ -49,26 +49,28 @@ class TestLshHash:
 
     def test_block_size_invariance(self):
         x, rot = randn(50, 64), randn(2, 1, 64, 64)
-        a = np.asarray(lsh_hash(x, rot, block_b=8))
-        b = np.asarray(lsh_hash(x, rot, block_b=64))
+        a = np.asarray(lsh_hash(x, rot, block_b=8, interpret=True))
+        b = np.asarray(lsh_hash(x, rot, block_b=64, interpret=True))
         assert (a == b).all()
 
     @pytest.mark.parametrize("T,K,NB", [(1, 1, 64), (4, 2, 256), (3, 3, 100)])
     def test_mix_epilogue_matches_host_mixing(self, T, K, NB):
         """lsh_hash_mix (in-kernel mixing) == lsh_hash + host modular steps."""
         x, rot = randn(20, 32), randn(T, K, 32, 32)
-        vids = np.asarray(lsh_hash(x, rot))
+        vids = np.asarray(lsh_hash(x, rot, interpret=True))
         radix = 2 * 32
         want = np.zeros(vids.shape[:-1], np.int32)
         for kk in range(K):
             want = (want * radix + vids[..., kk]) % NB
-        got = np.asarray(lsh_hash_mix(x, rot, num_buckets=NB))
+        got = np.asarray(lsh_hash_mix(x, rot, num_buckets=NB, interpret=True))
         assert (got == want).all()
 
     def test_mix_epilogue_block_invariance(self):
         x, rot = randn(50, 32), randn(2, 2, 32, 32)
-        a = np.asarray(lsh_hash_mix(x, rot, num_buckets=128, block_b=8))
-        b = np.asarray(lsh_hash_mix(x, rot, num_buckets=128, block_b=64))
+        a = np.asarray(lsh_hash_mix(x, rot, num_buckets=128, block_b=8,
+                                    interpret=True))
+        b = np.asarray(lsh_hash_mix(x, rot, num_buckets=128, block_b=64,
+                                    interpret=True))
         assert (a == b).all()
 
 
@@ -104,13 +106,13 @@ class TestSimTop1:
 
     def test_block_invariance(self):
         q, s = randn(32, 64), randn(700, 64)
-        v1, i1 = sim_top1(q, s, block_q=8, block_n=128)
-        v2, i2 = sim_top1(q, s, block_q=32, block_n=512)
+        v1, i1 = sim_top1(q, s, block_q=8, block_n=128, interpret=True)
+        v2, i2 = sim_top1(q, s, block_q=32, block_n=512, interpret=True)
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-6)
         assert (np.asarray(i1) == np.asarray(i2)).all()
 
 
-# ------------------------------------------------------------- gather_top1
+# ------------------------------------------- staged top-1 (gathered_top1)
 class TestGatherTop1:
     def _unit(self, *shape):
         x = randn(*shape)
@@ -145,8 +147,9 @@ class TestGatherTop1:
     def test_block_invariance(self):
         q, s = self._unit(40, 64), self._unit(500, 64)
         ids = jnp.asarray(RNG.integers(-1, 500, (40, 130)), jnp.int32)
-        v1, i1 = gather_top1(q, s, ids, block_q=8, block_c=32)
-        v2, i2 = gather_top1(q, s, ids, block_q=64, block_c=256)
+        v1, i1 = reuse_top1(q, s, ids, block_q=8, block_c=32, interpret=True)
+        v2, i2 = reuse_top1(q, s, ids, block_q=64, block_c=256,
+                            interpret=True)
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-6)
         assert (np.asarray(i1) == np.asarray(i2)).all()
 
@@ -182,8 +185,10 @@ class TestGatherTop1:
         paged = self._unit(P * S, D).reshape(P, S, D)
         q = self._unit(24, D)
         ids = jnp.asarray(RNG.integers(-1, P * S, (24, 70)), jnp.int32)
-        v1, i1 = gather_top1(q, paged, ids, block_q=8, block_c=32)
-        v2, i2 = gather_top1(q, paged, ids, block_q=32, block_c=128)
+        v1, i1 = reuse_top1(q, paged, ids, block_q=8, block_c=32,
+                            interpret=True)
+        v2, i2 = reuse_top1(q, paged, ids, block_q=32, block_c=128,
+                            interpret=True)
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-6)
         assert (np.asarray(i1) == np.asarray(i2)).all()
 
@@ -219,7 +224,7 @@ class TestReuseQueryTop1:
         q = self._unit(Q, D).astype(dtype)
         s = self._unit(N, D).astype(dtype)
         ids = jnp.asarray(RNG.integers(-1, N, (Q, C)), jnp.int32)
-        val, idx = reuse_top1(q, s, ids)
+        val, idx = reuse_top1(q, s, ids, interpret=True)
         wv, wi = ref.reuse_top1_ref(q, s, ids)
         fin = np.isfinite(np.asarray(wv))
         tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -238,7 +243,7 @@ class TestReuseQueryTop1:
         for order in ([40, 7, 3, 57, -1, 3], [57, 40, 3, 3, 7, -1],
                       [3, 57, 40, -1, -1, 7]):
             ids = jnp.asarray([order], jnp.int32)
-            _, idx = reuse_top1(q, jnp.asarray(s), ids)
+            _, idx = reuse_top1(q, jnp.asarray(s), ids, interpret=True)
             assert int(idx[0]) == 3, order
             _, wi = ref.reuse_top1_ref(q, jnp.asarray(s), ids)
             assert int(wi[0]) == 3, order
@@ -250,46 +255,53 @@ class TestReuseQueryTop1:
         s = np.array(self._unit(256, 32))
         s[200] = s[5]
         q = jnp.asarray(s[5:6])
-        ids = np.full((1, 128), -1, np.int32)
-        ids[0, 0] = 200            # tile 0 (block_c=64): high id first
-        ids[0, 100] = 5            # tile 1: the lower equal-sim id
+        ids = np.full((1, 256), -1, np.int32)
+        ids[0, 0] = 200            # tile 0 (block_c=128): high id first
+        ids[0, 200] = 5            # tile 1: the lower equal-sim id
         _, idx = reuse_top1(q, jnp.asarray(s), jnp.asarray(ids),
-                            block_c=64)
+                            block_c=128, interpret=True)
         assert int(idx[0]) == 5
 
-    def test_onehot_gather_matches_take(self):
-        q = self._unit(16, 32)
-        s = self._unit(128, 32)
-        ids = jnp.asarray(RNG.integers(-1, 128, (16, 40)), jnp.int32)
-        v1, i1 = reuse_top1(q, s, ids, gather_mode="take")
-        v2, i2 = reuse_top1(q, s, ids, gather_mode="onehot")
-        np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-5)
-        assert (np.asarray(i1) == np.asarray(i2)).all()
+    def test_query_chunks_match_reference(self, monkeypatch):
+        """A gather budget of one 8-row chunk splits the batch into several
+        kernel launches; the result must not depend on the split."""
+        Q, N, C, D = 37, 300, 130, 48
+        monkeypatch.setattr(sim_topk, "GATHER_BUDGET_BYTES", 8 * 256 * D * 4)
+        q, s = self._unit(Q, D), self._unit(N, D)
+        ids = jnp.asarray(RNG.integers(-1, N, (Q, C)), jnp.int32)
+        val, idx = reuse_top1(q, s, ids, block_q=8, block_c=128,
+                              interpret=True)
+        wv, wi = ref.reuse_top1_ref(q, s, ids)
+        fin = np.isfinite(np.asarray(wv))
+        np.testing.assert_allclose(np.asarray(val)[fin], np.asarray(wv)[fin],
+                                   atol=1e-5)
+        assert (np.asarray(idx) == np.asarray(wi)).all()
 
-    @pytest.mark.parametrize("gather_mode", ["take", "onehot"])
-    def test_paged_store_matches_flat(self, gather_mode):
-        P, S, D, C = 8, 32, 32, 40
+    @pytest.mark.parametrize("P,S,D,C", [(8, 32, 32, 40), (3, 128, 64, 200)])
+    def test_paged_store_matches_flat(self, P, S, D, C):
         flat = self._unit(P * S, D)
         paged = flat.reshape(P, S, D)
         q = self._unit(12, D)
         ids = jnp.asarray(RNG.integers(-1, P * S, (12, C)), jnp.int32)
-        fv, fi = reuse_top1(q, flat, ids, gather_mode=gather_mode)
-        pv, pi = reuse_top1(q, paged, ids, gather_mode=gather_mode)
+        fv, fi = reuse_top1(q, flat, ids, interpret=True)
+        pv, pi = reuse_top1(q, paged, ids, interpret=True)
         np.testing.assert_allclose(np.asarray(pv), np.asarray(fv), atol=1e-6)
         assert (np.asarray(pi) == np.asarray(fi)).all()
 
     def test_no_candidates_row(self):
         q, s = self._unit(4, 32), self._unit(64, 32)
         ids = jnp.full((4, 10), -1, jnp.int32)
-        val, idx = reuse_top1(q, s, ids)
+        val, idx = reuse_top1(q, s, ids, interpret=True)
         assert (np.asarray(idx) == -1).all()
         assert np.isneginf(np.asarray(val)).all()
 
     def test_block_invariance(self):
         q, s = self._unit(40, 64), self._unit(500, 64)
         ids = jnp.asarray(RNG.integers(-1, 500, (40, 130)), jnp.int32)
-        v1, i1 = reuse_top1(q, s, ids, block_q=8, block_c=32)
-        v2, i2 = reuse_top1(q, s, ids, block_q=64, block_c=256)
+        v1, i1 = reuse_top1(q, s, ids, block_q=8, block_c=128,
+                            interpret=True)
+        v2, i2 = reuse_top1(q, s, ids, block_q=64, block_c=256,
+                            interpret=True)
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-6)
         assert (np.asarray(i1) == np.asarray(i2)).all()
 
@@ -313,12 +325,14 @@ class TestReuseQueryTop1:
         proj = lsh.rotations if family == "cross_polytope" else lsh.planes
         val, idx, counts = fused_query(
             jnp.asarray(embs), proj, jnp.asarray(slots), jnp.asarray(pages),
-            family=family, num_probes=P_probe, with_counts=True)
+            family=family, num_probes=P_probe, interpret=True,
+            with_counts=True)
         # the with_counts=False variant hands the raw candidate matrix back
         # for host-side counting — same results, bit-identical counts
         val2, idx2, cand2 = fused_query(
             jnp.asarray(embs), proj, jnp.asarray(slots), jnp.asarray(pages),
-            family=family, num_probes=P_probe, with_counts=False)
+            family=family, num_probes=P_probe, interpret=True,
+            with_counts=False)
         assert (np.asarray(idx2) == np.asarray(idx)).all()
         assert (np.asarray(val2) == np.asarray(val)).all()
         assert (ops.unique_counts(np.asarray(cand2))
@@ -370,8 +384,8 @@ class TestFlashAttention:
 
     def test_block_invariance(self):
         q, k, v = randn(1, 64, 4, 32), randn(1, 64, 4, 32), randn(1, 64, 4, 32)
-        a = flash_attention(q, k, v, block_q=8, block_k=8)
-        b = flash_attention(q, k, v, block_q=64, block_k=64)
+        a = flash_attention(q, k, v, block_q=8, block_k=8, interpret=True)
+        b = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
     def test_matches_model_attention_math(self):

@@ -223,7 +223,7 @@ def run_interleaving(seed: int, kernel: bool = False,
     """One random op interleaving, store vs model, state-checked per op.
 
     ``kernel=True`` routes every batched score through the staged
-    ``gather_top1`` device path; ``fused=True`` routes every ``query_batch``
+    ``reuse_top1`` device path; ``fused=True`` routes every ``query_batch``
     through the one-dispatch ``reuse_query_top1`` pipeline (device slot
     tables + paged buffer), checking hit/miss, similarity, tie-break,
     tombstone, and LRU parity against the RefStore per op.
@@ -323,8 +323,8 @@ class TestStoreProperties:
 
     @pytest.mark.parametrize("seed", range(8))
     def test_interleaving_parity_kernel_path(self, seed):
-        # use_kernel_threshold=1: every batched score runs the fused
-        # gather_top1 kernel against the paged device buffer
+        # use_kernel_threshold=1: every batched score runs the staged
+        # reuse_top1 kernel against the paged device buffer
         run_interleaving(1000 + seed, kernel=True)
 
     @pytest.mark.parametrize("seed", range(200))
