@@ -1,0 +1,134 @@
+"""The served model behind the replicas, built through the program's API.
+
+``repro.configs.get_arch`` gives the architecture, cut in depth and set to
+the configuration file's numbers with ``dataclasses.replace``;
+``repro.models.build_model`` builds it, and its jitted ``prefill`` serves a
+replica's miss group as one call.  The weights are the benchmark's own
+(``reference.decoder.make_weights``), made on the device from the seed and
+checked against the layout of the program's ``init``.
+
+Rows of a group are padded to the next of the configuration's batch sizes;
+the function returns the argmax token of each real row.  How the program
+forms groups (batcher, ``max_batch``, flush window) is the program's; that
+the group is one model call is fixed here, so a change to how the model is
+called for a group needs a service executor inside the program first.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bench.reference import decoder
+
+
+def arch_config(cfg: dict):
+    """The program's ArchConfig for a configuration file, checked against
+    the file's numbers."""
+    from repro.configs import get_arch
+
+    m = cfg["model"]
+    arch = dataclasses.replace(
+        get_arch(cfg["arch"]), n_layers=m["num_hidden_layers"],
+        norm_eps=m["rms_norm_eps"], rope_theta=float(m["rope_theta"]),
+        dtype=m["torch_dtype"])
+    want = {"d_model": m["hidden_size"], "d_ff": m["intermediate_size"],
+            "n_heads": m["num_attention_heads"],
+            "n_kv_heads": m["num_key_value_heads"],
+            "resolved_head_dim": m["head_dim"], "vocab_size": m["vocab_size"],
+            "tie_embeddings": m["tie_word_embeddings"],
+            "qk_norm": m["qk_norm"], "qkv_bias": m.get("attention_bias", False),
+            "n_frontend_tokens": m["frontend_tokens"], "mlp_act": "silu",
+            "layer_pattern": ("global",), "n_experts": 0,
+            "attn_logit_softcap": None, "final_logit_softcap": None,
+            "sliding_window": None, "scale_embeddings": False,
+            "use_post_norms": False}
+    bad = {k: (getattr(arch, k), v) for k, v in want.items()
+           if getattr(arch, k) != v}
+    if bad:
+        raise ValueError(f"program's {cfg['arch']} differs from the "
+                         f"configuration file: {bad}")
+    return arch
+
+
+class Service:
+    """Weights, the program's jitted prefill, and the replicas' execute_fn."""
+
+    def __init__(self, cfg: dict, key, model_override=None):
+        from repro.models import build_model
+
+        self.cfg = cfg
+        self.model_cfg = cfg["model"]
+        self.arch = model_override or arch_config(cfg)
+        self.model = build_model(self.arch)
+        self.weights = decoder.make_weights(self.model_cfg, key)
+        want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
+        got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                           self.weights)
+        if jax.tree.structure(want) != jax.tree.structure(got) or any(
+                a.shape != b.shape or a.dtype != b.dtype for a, b in
+                zip(jax.tree.leaves(want), jax.tree.leaves(got))):
+            raise ValueError("the benchmark's weight layout differs from the "
+                             "program's init")
+        svc = cfg["service"]
+        self.prompt = svc["prompt_tokens"]
+        self.pool_size = svc["image_pool"]
+        self.front = self.model_cfg["frontend_tokens"]
+        self.batch_sizes = sorted(cfg["engine"]["batch_sizes"])
+        self.images = None
+        if self.pool_size:
+            self.images = _image_pool(
+                jax.random.fold_in(key, 1), self.pool_size, self.front,
+                self.model_cfg["hidden_size"], jnp.dtype(self.arch.dtype))
+        model, max_len = self.model, self.prompt + self.front + 8
+
+        @jax.jit
+        def serve(params, tokens, image_idx, images):
+            batch = {"tokens": tokens}
+            if images is not None:
+                batch["patch_embeds"] = images[image_idx]
+            logits, _ = model.prefill(params, batch, max_len)
+            return jnp.argmax(logits[:, -1], axis=-1)
+
+        self._serve = serve
+        self.calls: List[tuple] = []   # (real rows, padded rows) per call
+
+    def padded(self, n: int) -> int:
+        for b in self.batch_sizes:
+            if n <= b:
+                return b
+        raise ValueError(f"group of {n} exceeds the largest batch "
+                         f"{self.batch_sizes[-1]}")
+
+    def run(self, tokens: np.ndarray, image: np.ndarray) -> np.ndarray:
+        """(n, prompt) tokens and (n,) image ids -> (n,) served tokens."""
+        n = tokens.shape[0]
+        b = self.padded(n)
+        tok = np.zeros((b, self.prompt), np.int32)
+        tok[:n] = tokens
+        img = np.zeros((b,), np.int32)
+        img[:n] = np.maximum(image, 0)
+        out = self._serve(self.weights, tok, img, self.images)
+        self.calls.append((n, b))
+        return np.asarray(out)[:n]
+
+    def execute(self, reqs) -> List[int]:
+        """A replica's ``execute_fn``: one prefill call for the group."""
+        tokens = np.stack([r.payload["tokens"] for r in reqs])
+        image = np.asarray([r.payload["image"] for r in reqs], np.int32)
+        return [int(t) for t in self.run(tokens, image)]
+
+    def warm(self) -> None:
+        for b in self.batch_sizes:
+            self.run(np.zeros((b, self.prompt), np.int32),
+                     np.zeros((b,), np.int32))
+        self.calls.clear()
+
+
+def _image_pool(key, n: int, front: int, d: int, dtype) -> jax.Array:
+    """(n, front, d) patch embeddings in the type the model takes them."""
+    return jax.jit(lambda k: jax.random.normal(k, (n, front, d), dtype),
+                   static_argnums=())(key)

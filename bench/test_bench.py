@@ -1,0 +1,237 @@
+"""CPU tests of the benchmark: its arithmetic, its generator, its trace
+reduction, its refusal of a machine without a chip, and its check, which
+must pass a sound run and fail a run with a fault planted in the timed
+path.  The cells' real sizes run only on the chip; here a tiny model of the
+same family stands in."""
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from bench import flops, generator, harness
+from bench import trace as tr
+from bench.reference import decoder
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _config(name: str) -> dict:
+    with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+# ------------------------------------------------------------- arithmetic
+def test_prefill_flops_qwen3_by_hand():
+    # per layer at S=32: projections 2*32*2048*(16+2*8)*128 + 2*32*16*128*2048
+    # = 805,306,368; causal attention 4 * (32*33/2) * 16 * 128 = 4,325,376;
+    # MLP 2*32*2048*(2*6144) + 2*32*6144*2048 = 2,415,919,104.  28 layers and
+    # the last position's logits 2*2048*151936.
+    assert flops.prefill_flops(_config("qwen3-1.7b")["model"], 32) == \
+        28 * (805_306_368 + 4_325_376 + 2_415_919_104) + 622_329_856
+
+
+def test_prefill_flops_phi3v_by_hand():
+    # per layer at S=576+32=608: projections 2*608*3072*(32+2*32)*96
+    # + 2*608*32*96*3072 = 45,902,462,976; causal attention
+    # 4 * (608*609/2) * 32 * 96 = 2,274,951,168; MLP 2*608*3072*(2*8192)
+    # + 2*608*8192*3072 = 91,804,925,952.  16 layers and 2*3072*32064.
+    assert flops.prefill_flops(_config("phi3v-l16")["model"], 608) == \
+        16 * (45_902_462_976 + 2_274_951_168 + 91_804_925_952) + 197_001_216
+
+
+def test_search_counts_by_hand():
+    st = _config("qwen3-1.7b")["store"]
+    assert flops.hash_flops(st) == 2 * 5 * 64 * 64
+    assert flops.cosine_flops(st, 20480) == 2 * 64 * 20480
+
+
+def test_peaks_table_refuses_unknown_device():
+    from bench.peaks import peaks_for
+
+    assert peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(KeyError):
+        peaks_for("cpu")
+
+
+# -------------------------------------------------------------- generator
+def test_generator_repeats_for_a_seed_and_differs_across_seeds():
+    traffic = {"stream": "cctv1", "rate_per_s": 100.0}
+    a = generator.segment(traffic, 200, 2.0, 2**31 + 5, "window", 32, 151936, 0)
+    b = generator.segment(traffic, 200, 2.0, 2**31 + 5, "window", 32, 151936, 0)
+    c = generator.segment(traffic, 200, 2.0, 2**31 + 6, "window", 32, 151936, 0)
+    np.testing.assert_array_equal(a.emb, b.emb)
+    np.testing.assert_array_equal(a.due, b.due)
+    np.testing.assert_array_equal(a.tokens, b.tokens)
+    assert not np.array_equal(a.emb, c.emb)
+    assert not np.array_equal(a.due, c.due)
+    assert a.due.min() >= 0 and a.due.max() < 2.0 and np.all(np.diff(a.due) >= 0)
+    np.testing.assert_allclose(np.linalg.norm(a.emb, axis=1), 1.0, rtol=1e-5)
+
+
+def test_generator_counts_are_fixed_by_rate_and_seconds():
+    n, warm, warm_s = generator.window_counts(
+        {"rate_per_s": 250.0, "warmup_tasks": 100}, 20.0)
+    assert (n, warm, warm_s) == (5000, 100, 0.4)
+
+
+# ---------------------------------------------------------- trace reduction
+def _synthetic_trace():
+    ms = 1e6
+    host = [("bench/window", 0.0, 100 * ms), ("bench/search", 10 * ms, 20 * ms),
+            ("bench/reuse_top1", 25 * ms, 4 * ms),
+            ("bench/execute", 50 * ms, 30 * ms)]
+    device = [("fusion.1", -5 * ms, 10 * ms),        # starts before the window
+              ("reuse_top1", 26 * ms, 2 * ms),
+              ("convolution.3", 55 * ms, 10 * ms),
+              ("fusion.2", 60 * ms, 15 * ms),       # overlaps the one before
+              ("fusion.9", 150 * ms, 5 * ms)]       # after the window
+    return {"devices": [device], "host": host}
+
+
+def test_trace_reduction_on_a_synthesised_trace():
+    red = tr.reduce(_synthetic_trace())
+    assert red["window_s"] == pytest.approx(0.1)
+    # busy: [0,5] + [26,28] + [55,75] ms
+    assert red["busy_s"] == pytest.approx(0.027)
+    assert red["ops"]["reuse_top1"] == pytest.approx(0.002)
+    assert red["ops"]["fusion.1"] == pytest.approx(0.005)
+    assert "fusion.9" not in red["ops"]
+    # gaps: [5,26] mid 15.5 in search; [28,55] mid 41.5 in nothing;
+    # [75,100] mid 87.5 in nothing
+    assert red["idle"]["search"] == pytest.approx(0.021)
+    assert red["idle"]["loop"] == pytest.approx(0.027 + 0.025)
+    assert tr.top(red["idle"], 1)[0][0] == "loop"
+
+
+def test_trace_reduction_picks_the_innermost_span():
+    ms = 1e6
+    host = [("bench/window", 0.0, 10 * ms), ("bench/search", 0.0, 10 * ms),
+            ("bench/reuse_top1", 2 * ms, 6 * ms)]
+    red = tr.reduce({"devices": [[("op", 0.0, 1 * ms)]], "host": host})
+    assert red["idle"] == {"reuse_top1": pytest.approx(0.009)}
+
+
+# ------------------------------------------------------------ no chip here
+def test_measurement_path_refuses_the_cpu(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "bench", "run.py"), "--workload",
+         "phi3v-l16.pandaset.over", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=ROOT, env=env, capture_output=True, text=True,
+        timeout=300)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" in p.stderr
+
+
+# ------------------------------------------------------- the check, tiny
+def _tiny(cfg_name: str, arch_name: str, pool: int):
+    from repro.configs import get_arch
+
+    cfg = _config(cfg_name)
+    arch = get_arch(arch_name).reduced()
+    cfg["model"].update({
+        "num_hidden_layers": arch.n_layers, "hidden_size": arch.d_model,
+        "intermediate_size": arch.d_ff, "num_attention_heads": arch.n_heads,
+        "num_key_value_heads": arch.n_kv_heads,
+        "head_dim": arch.resolved_head_dim, "vocab_size": arch.vocab_size,
+        "rms_norm_eps": arch.norm_eps, "rope_theta": arch.rope_theta,
+        "frontend_tokens": arch.n_frontend_tokens})
+    cfg["service"]["image_pool"] = pool
+    cfg["check"].update(executed_sample=16, query_sample=64)
+    return cfg, arch
+
+
+TINY = {
+    # reuse-heavy: a CCTV stream over a small history
+    "qwen": ("phi3v-l16.pandaset.over", "qwen3-1.7b", "qwen3-1.7b", 0,
+             {"stream": "cctv1", "threshold": 0.9, "rate_per_s": 60.0,
+              "history": 600, "warmup_tasks": 10, "drain_s": 60.0}),
+    # execution-heavy: i.i.d. frames with images, no history
+    "phi": ("phi3v-l16.pandaset.over", "phi3v-l16", "phi-3-vision-4.2b", 4,
+            {"stream": "pandaset", "threshold": 0.9, "rate_per_s": 60.0,
+             "history": 0, "warmup_tasks": 10, "drain_s": 60.0}),
+}
+
+
+def _tiny_run(which: str, fault=None, control=False, seed=2**31 + 11,
+              check=None):
+    workload, cfg_name, arch_name, pool, traffic = TINY[which]
+    cfg, arch = _tiny(cfg_name, arch_name, pool)
+    cfg["check"].update(check or {})
+    stats: dict = {}
+    out = harness.run(workload, seed, 1.5, False, t_start=time.perf_counter(),
+                      require_tpu=False, fault=fault, config_override=cfg,
+                      traffic_override=traffic, model_override=arch,
+                      stats=stats, control=control)
+    return out, stats
+
+
+@pytest.mark.parametrize("which,fault", [
+    ("qwen", None), ("phi", None),
+    ("phi", "token"),    # a served token altered where it is produced
+    ("phi", "half"),     # half of each executed group left out
+    ("qwen", "store"),   # store answers altered where they are produced
+])
+def test_check_passes_sound_runs_and_fails_planted_faults(which, fault):
+    out, stats = _tiny_run(which, fault)
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert list(out)[-1] == "checks"
+    assert out["correct"] is (fault is None), out["checks"]
+    if which == "qwen":
+        assert stats["served"].count("cs") + stats["served"].count("en") > 0
+    else:
+        assert stats["served"].count(None) > 0
+
+
+def test_float8_control_reads_wider_gaps_than_the_program():
+    # at this size the program reads a gap of 0 and the control 0.26-0.50
+    # over 64 executed tasks, so the full-width model's limit does not apply
+    out, stats = _tiny_run("phi", control=True, check={
+        "logit_gap_limit": 0.02, "executed_sample": 64})
+    assert out["correct"] is False
+    assert out["checks"]["logit_gap"]["value"] > out["checks"]["logit_gap"]["limit"]
+    assert stats["checks"]["logit_gap"][0] > 3 * stats["program_gap"]
+    assert stats["program_gap"] <= out["checks"]["logit_gap"]["limit"]
+
+
+def test_reference_weight_layout_matches_the_program_init():
+    import jax
+
+    from bench import service
+
+    cfg, arch = _tiny("phi3v-l16", "phi-3-vision-4.2b", 0)
+    svc = service.Service(cfg, jax.random.PRNGKey(0), model_override=arch)
+    assert set(svc.weights) == {"embed", "head", "final_norm", "layers_0"}
+    assert decoder.shapes(cfg["model"])["layers_0"]["attn"]["wq"] == (2, 64, 64)
+
+
+# ------------------------------------------------------------ the data
+def test_benchmark_file_names_existing_files():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for c in bench["configs"]:
+        assert os.path.exists(os.path.join(ROOT, c["file"]))
+    for w in bench["workloads"]:
+        generator.load_traffic(w["traffic"])
+    for m in bench["per_layer"]:
+        harness.load_reader(m["name"])
+    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert len(names) == len(set(names))
+
+
+def test_device_ops_are_named_by_module_and_kernel_tag():
+    mods = [(0.0, 10.0, "jit_serve"), (20.0, 5.0, "jit_reuse_top1")]
+    kernel = ('%closed_call.7 = (f32[8,1]) custom-call(f32[8,64] %a), '
+              'custom_call_target="tpu_custom_call", operand_layout_constraints={}')
+    assert tr.op_name(kernel, 21.0, mods) == \
+        "jit_reuse_top1:%closed_call.7[tpu_custom_call]"
+    assert tr.op_name("%fusion.3 = bf16[8] fusion(%x)", 2.0, mods) == \
+        "jit_serve:%fusion.3"
+    assert tr.op_name("%copy.1 = f32[1] copy(%x)", 15.0, mods) == "%copy.1"
