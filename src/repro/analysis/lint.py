@@ -51,7 +51,7 @@ O-class — observability rules (metrics-registry hygiene):
   ``fault_stats``) inside a sim-path package.  Those mappings are
   ``repro.obs.registry.CounterGroup`` views adopted by the one
   ``MetricsRegistry``; write through ``.inc(key, n)`` so every increment
-  is a registry event the per-interval snapshots can see.  Tests and
+  goes through the counters' one write path.  Tests and
   benchmarks may still poke the mapping (CounterGroup stays a
   MutableMapping for exactly that reason).
 
@@ -378,7 +378,7 @@ class _Checker(ast.NodeVisitor):
                   f"direct mutation of '.{tgt.value.attr}[...]': this "
                   "mapping is a CounterGroup adopted by the metrics "
                   "registry; write through .inc(key, n) so the increment "
-                  "is visible to per-interval snapshots")
+                  "goes through the counters' one write path")
 
     def visit_Assign(self, node: ast.Assign) -> None:
         if _is_set_expr(node.value, self.scopes[-1]):

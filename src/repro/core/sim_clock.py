@@ -119,6 +119,22 @@ class EventLoop:
         """The armed Tracer, or None when disarmed."""
         return self._tracer
 
+    def arm_tracer(self, clock: str = "virtual") -> _trace.Tracer:
+        """Arm a fresh Tracer on this loop, stamping ``clock`` ("virtual" or
+        "host"): a loop driven in real time arms a host-clock tracer for the
+        stretch it measures.  Replaces any armed tracer."""
+        self.disarm_tracer()
+        self._tracer = _trace.Tracer(self, clock)
+        return self._tracer
+
+    def disarm_tracer(self) -> Optional[_trace.Tracer]:
+        """Detach and close the armed Tracer (None when disarmed); its
+        events stay readable on the returned object."""
+        tr, self._tracer = self._tracer, None
+        if tr is not None:
+            tr.close()
+        return tr
+
     @property
     def profiler(self) -> Optional[_profiler.Profiler]:
         """The armed Profiler, or None when disarmed."""

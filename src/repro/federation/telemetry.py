@@ -79,14 +79,6 @@ class TelemetryGossip:
                  for node in self.net.en_nodes}
         for node in snaps:
             self.last_publish[node] = now
-        reg = getattr(self.net, "registry", None)
-        if reg is not None:
-            # the gossip cadence is the metrics-snapshot cadence: one
-            # per-interval registry row per round, load gauges included
-            for node, snap in snaps.items():
-                reg.gauge(f"load/{node}/depth").set(snap.depth)
-                reg.gauge(f"load/{node}/service_s").set(snap.service_s)
-            reg.snapshot(now)
         tr = self.net.loop.tracer
         if tr is not None:
             tr.instant("gossip-round", "gossip", tr.track("gossip"),

@@ -5,7 +5,9 @@ Three independent pieces sharing the sanitizer's arming discipline
 
 * ``trace``    — per-task distributed tracing on the virtual timeline,
                  exported as Chrome trace-event / Perfetto JSON.  Armed via
-                 ``RESERVOIR_TRACE=1`` or ``EventLoop(trace=True)``;
+                 ``RESERVOIR_TRACE=1`` or ``EventLoop(trace=True)``, or on
+                 the host clock with ``EventLoop.arm_tracer("host")``,
+                 whose scoped spans also land in the JAX profiler trace;
                  disarmed it is a ``None`` attribute and costs one attribute
                  test per hook site.
 * ``registry`` — unified counters/gauges/histograms.  ALWAYS ON: purely

@@ -9,9 +9,7 @@ randomness, and schedules zero events, so the seeded bit-for-bit goldens
 
 Hot-path discipline: ``Histogram.observe`` is allocation-free (a bisect over
 a fixed edge tuple plus integer bumps), ``Counter.inc``/``CounterGroup.inc``
-are single dict/int operations.  Per-interval time-series snapshots ride the
-federation gossip cadence (``TelemetryGossip.publish_now`` calls
-``snapshot``) or any manual ``snapshot(t)``.
+are single dict/int operations.
 
 ``CounterGroup`` subclasses ``MutableMapping`` so every existing accessor —
 ``stats["reused"]``, ``dict(stats)``, ``stats.values()``, equality against a
@@ -155,14 +153,13 @@ class CounterGroup(MutableMapping):
 
 class MetricsRegistry:
     """The single sink: named counters/gauges/histograms plus adopted
-    ``CounterGroup``s, with per-interval time-series snapshots."""
+    ``CounterGroup``s."""
 
     def __init__(self) -> None:
         self.counters: Dict[str, Counter] = {}
         self.gauges: Dict[str, Gauge] = {}
         self.histograms: Dict[str, Histogram] = {}
         self.groups: Dict[str, CounterGroup] = {}
-        self.series: List[Dict[str, Any]] = []
 
     # --------------------------------------------------------- get-or-create
     def counter(self, name: str) -> Counter:
@@ -210,28 +207,10 @@ class MetricsRegistry:
             out[f"{p}_n"] = h.count if h else 0
         return out
 
-    # -------------------------------------------------------------- snapshot
-    def snapshot(self, t: float) -> Dict[str, Any]:
-        """Append one time-series sample (called on the gossip cadence)."""
-        snap: Dict[str, Any] = {"t": t}
-        for name, c in self.counters.items():
-            snap[name] = c.value
-        for name, g in self.gauges.items():
-            snap[name] = g.value
-        for name, h in self.histograms.items():
-            snap[f"{name}/count"] = h.count
-            snap[f"{name}/sum"] = h.sum
-        for gname, grp in self.groups.items():
-            for k, v in grp.items():
-                snap[f"{gname}/{k}"] = v
-        self.series.append(snap)
-        return snap
-
     def to_dict(self) -> Dict[str, Any]:
         return {
             "counters": {k: c.value for k, c in self.counters.items()},
             "gauges": {k: g.value for k, g in self.gauges.items()},
             "histograms": {k: h.to_dict() for k, h in self.histograms.items()},
             "groups": {k: dict(g) for k, g in self.groups.items()},
-            "series": list(self.series),
         }

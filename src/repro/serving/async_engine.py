@@ -31,9 +31,23 @@ there); when absent, the measured wall time of ``execute_fn`` is used, so
 real-model runs keep physical timing.  The sync ``ServingFleet.submit`` /
 ``submit_batch`` APIs are thin wrappers over this engine with a drained
 loop (``engine.py``), which is what makes scalar parity testable.
+
+Observability: with a tracer armed on the loop (DESIGN.md §Observability)
+every request is traced at the engine's stage boundaries — scoped
+``engine/admit`` (with ``engine/route``), ``engine/dispatch`` (with
+``engine/search`` and ``engine/execute``) and ``engine/commit`` spans, and
+one async ``engine/queue`` span per leader from admission to the dispatch
+that carries it, closed with the flush reason.  Per-task spans sit on the
+request's track (its ``trace_tid`` where the network gave one, else its
+request id), per-group spans on an ``engine/r<replica>`` track.  Disarmed,
+each hook site is one ``tracer is None`` test.  The ``exec_rows`` and
+``discarded_rows`` counters are always on: rows sent to the model, and rows
+whose result was thrown away because another execution had already
+answered the task.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import random
@@ -58,6 +72,20 @@ from repro.training.elastic import BackupPolicy
 from .batcher import Batcher
 from .engine import ReplicaEngine, ReuseRouter, ServeRequest, ServeResult
 
+#: What a hook site enters when no tracer is armed.
+_UNTRACED = contextlib.nullcontext()
+
+
+def _scope(tr: Any, name: str, tid: int, **args: Any):
+    """``tr.span(name, "engine", tid, **args)``, or a no-op context when
+    ``tr`` is None; ``with`` binds the span's args dict, or None."""
+    return _UNTRACED if tr is None else tr.span(name, "engine", tid, **args)
+
+
+def _tid(req: ServeRequest) -> int:
+    """A request's trace track: the originating task's, else its own id."""
+    return req.request_id if req.trace_tid is None else req.trace_tid
+
 
 @dataclasses.dataclass
 class _Task:
@@ -75,6 +103,7 @@ class _Task:
         default_factory=list)
     dispatched: List[int] = dataclasses.field(default_factory=list)
     backups_sent: int = 0
+    queue_span: Any = None         # (tracer, span id) while batched, traced
 
     @property
     def key(self) -> Tuple[int, str]:
@@ -110,7 +139,8 @@ class AsyncServingEngine:
         self._queued: Dict[int, _Task] = {}  # id(req) -> task while batched
         self._flush_timers: Dict[Tuple[int, str], Timer] = {}
         self.engine_stats = CounterGroup(
-            {"backups": 0, "backup_wins": 0, "dispatches": 0})
+            {"backups": 0, "backup_wins": 0, "dispatches": 0,
+             "exec_rows": 0, "discarded_rows": 0})
 
     # --------------------------------------------------------------- submit
     def submit(self, req: ServeRequest) -> Future:
@@ -126,8 +156,21 @@ class AsyncServingEngine:
         return fut
 
     def _admit(self, req: ServeRequest, fut: Future) -> None:
+        tr = self.loop.tracer
+        if tr is None:
+            self._place(req, fut, None)
+            return
+        with tr.span("engine/admit", "engine", _tid(req)) as args:
+            args["replica"], args["outcome"] = self._place(req, fut, tr)
+
+    def _place(self, req: ServeRequest, fut: Future,
+               tr: Any) -> Tuple[int, str]:
+        """Route a request, then answer it from the Content Store, attach it
+        to an in-flight leader, or queue it as a leader; returns (replica,
+        outcome ``cs``/``follower``/``leader``)."""
         t = self.loop.now
-        rid, buckets = self.router.route(req.embedding)  # one hash dispatch
+        with _scope(tr, "engine/route", _tid(req)):
+            rid, buckets = self.router.route(req.embedding)  # one hash
         rep = self.replicas[rid]
         name = rep.name_of(req.service, buckets)
 
@@ -137,24 +180,28 @@ class AsyncServingEngine:
             fut.try_set_result(
                 ServeResult(req.request_id, content, "cs", 1.0, 0.0, rid),
                 now=t)
-            return
+            return rid, "cs"
         # 2. PIT coalescing: attach as follower on the leader's future
         task = self._inflight.get((rid, name))
         if task is not None:
             rep.stats.inc("aggregated")
             task.followers.append((req, t, fut))
-            return
+            return rid, "follower"
         # 3. new leader: register in-flight, queue for a batched flush
         emb = normalize(np.asarray(req.embedding, np.float32).reshape(-1))
         task = _Task(req, name, emb, np.asarray(buckets), t, fut, rid,
                      req.service)
+        if tr is not None:
+            task.queue_span = (tr, tr.begin("engine/queue", "engine",
+                                            _tid(req), replica=rid))
         self._inflight[(rid, name)] = task
         self._queued[id(req)] = task
         key = (rid, req.service)
         full = self.batcher.add(req, t, key=key)
         if full is not None:
-            self._dispatch(rid, req.service, self._tasks_of(full), t)
+            self._dispatch(rid, req.service, self._tasks_of(full), t, "full")
         self._sync_flush_timer(key)
+        return rid, "leader"
 
     def _tasks_of(self, reqs: List[ServeRequest]) -> List[_Task]:
         return [self._queued.pop(id(r)) for r in reqs]
@@ -181,28 +228,50 @@ class AsyncServingEngine:
         rid, service = key
         if self.batcher.pending(key):
             reqs = self.batcher.flush(key, self.loop.now)
-            self._dispatch(rid, service, self._tasks_of(reqs), self.loop.now)
+            self._dispatch(rid, service, self._tasks_of(reqs), self.loop.now,
+                           "timer")
         self._sync_flush_timer(key)
 
     # ------------------------------------------------------------- pipeline
     def _dispatch(self, exec_rid: int, service: str, tasks: List[_Task],
-                  t: float) -> None:
+                  t: float, reason: str) -> None:
         """One pipeline pass on ``exec_rid``: batched EN query, then execute
-        the misses as one model batch with a deferred completion event."""
+        the misses as one model batch with a deferred completion event.
+        ``reason`` is what sent the group: ``full``, ``timer`` or
+        ``backup``."""
+        tr = self.loop.tracer
+        for task in tasks:
+            if task.queue_span is not None:
+                owner, sid = task.queue_span
+                task.queue_span = None
+                if owner is tr:
+                    tr.end(sid, reason=reason, replica=exec_rid)
         tasks = [task for task in tasks if not task.future.done]
         if not tasks:
             return
+        if tr is None:
+            self._run_group(exec_rid, service, tasks, t, None, None)
+            return
+        track = tr.track(f"engine/r{exec_rid}")
+        with tr.span("engine/dispatch", "engine", track, replica=exec_rid,
+                     rows=len(tasks), reason=reason, backup=reason == "backup",
+                     tasks=[_tid(task.req) for task in tasks]):
+            self._run_group(exec_rid, service, tasks, t, tr, track)
+
+    def _run_group(self, exec_rid: int, service: str, tasks: List[_Task],
+                   t: float, tr: Any, track: Optional[int]) -> None:
+        """The body of ``_dispatch``: search, then execute the misses."""
         rep = self.replicas[exec_rid]
         self.engine_stats.inc("dispatches")
-        tr = self.loop.tracer
         for task in tasks:
             task.dispatched.append(exec_rid)
-            if tr is not None and task.req.trace_tid is not None:
-                tr.instant("engine-dispatch", "engine", task.req.trace_tid,
-                           replica=exec_rid, task=task.req.trace_tid)
         embs = np.stack([task.emb for task in tasks])
         thrs = np.asarray([task.req.threshold for task in tasks], np.float32)
-        out = rep.query_reuse(service, embs, thrs)
+        with _scope(tr, "engine/search", track, queries=len(tasks)) as args:
+            out = rep.query_reuse(service, embs, thrs)
+            if args is not None:
+                args["path"] = ("fused" if rep.stores[service].last_query_fused
+                                else "staged")
         missed: List[_Task] = []
         for task, (result, sim, idx) in zip(tasks, out):
             if idx is not None:
@@ -225,7 +294,9 @@ class AsyncServingEngine:
                 missed.append(task)
         if not missed:
             return
-        outs, wall = rep.execute_batch([task.req for task in missed])
+        self.engine_stats.inc("exec_rows", len(missed))
+        with _scope(tr, "engine/execute", track, rows=len(missed)):
+            outs, wall = rep.execute_batch([task.req for task in missed])
         duration = (wall if self.exec_time_fn is None else
                     self.exec_time_fn(exec_rid, service,
                                       [task.req for task in missed]))
@@ -254,14 +325,20 @@ class AsyncServingEngine:
         t = self.loop.now
         live = [(task, res) for task, res in zip(tasks, outs)
                 if not task.future.done]
+        self.engine_stats.inc("discarded_rows", len(tasks) - len(live))
         if not live:
             return
         rep = self.replicas[exec_rid]
-        rep.commit_execution(
-            service, np.stack([task.emb for task, _ in live]),
-            [task.name for task, _ in live], [res for _, res in live],
-            t, duration * len(live) / len(tasks),
-            buckets=np.stack([task.buckets for task, _ in live]))
+        tr = self.loop.tracer
+        track = None if tr is None else tr.track(f"engine/r{exec_rid}")
+        with _scope(tr, "engine/commit", track, rows=len(live)) as args:
+            pages = rep.commit_execution(
+                service, np.stack([task.emb for task, _ in live]),
+                [task.name for task, _ in live], [res for _, res in live],
+                t, duration * len(live) / len(tasks),
+                buckets=np.stack([task.buckets for task, _ in live]))
+            if args is not None:
+                args["sync_pages"] = pages
         for task, res in live:
             is_backup = exec_rid != task.primary
             if is_backup:
@@ -270,7 +347,6 @@ class AsyncServingEngine:
                 self.replicas[task.primary].cs.insert(
                     Data(task.name, content=res), t)
                 self.engine_stats.inc("backup_wins")
-                tr = self.loop.tracer
                 if tr is not None and task.req.trace_tid is not None:
                     tr.instant("backup-win", "engine", task.req.trace_tid,
                                replica=exec_rid, task=task.req.trace_tid,
@@ -314,7 +390,7 @@ class AsyncServingEngine:
             tr.instant("backup", "engine", task.req.trace_tid,
                        replica=rid, attempt=task.backups_sent,
                        task=task.req.trace_tid)
-        self._dispatch(rid, task.service, [task], self.loop.now)
+        self._dispatch(rid, task.service, [task], self.loop.now, "backup")
 
     # ------------------------------------------------------------ crash-stop
     def abort_all(self, exc: Optional[BaseException] = None) -> None:
@@ -331,6 +407,10 @@ class AsyncServingEngine:
             timer.cancel()
         self._flush_timers.clear()
         self.batcher.queues.clear()
+        tr = self.loop.tracer
+        for task in self._queued.values():
+            if task.queue_span is not None and task.queue_span[0] is tr:
+                tr.abandon(task.queue_span[1], why="aborted")
         self._queued.clear()
         for task in list(self._inflight.values()):
             self.backup.cancel(task.key)

@@ -32,8 +32,6 @@ class Batcher:
         self.max_batch = max_batch
         self.max_wait_s = max_wait_s
         self.queues: Dict[Hashable, List[PendingEntry]] = {}
-        self.flushes = 0
-        self.batched_total = 0
 
     def add(self, req: ServeRequest, now: float,
             key: Optional[Hashable] = None) -> Optional[List[ServeRequest]]:
@@ -76,8 +74,6 @@ class Batcher:
             self.queues[key] = rest
         else:
             self.queues.pop(key, None)
-        self.flushes += 1
-        self.batched_total += len(batch)
         return [e.req for e in batch]
 
     def flush_due(self, now: float) -> Dict[Hashable, List[ServeRequest]]:

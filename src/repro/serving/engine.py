@@ -136,9 +136,10 @@ class ReplicaEngine:
     def commit_execution(self, service: str, embs: np.ndarray,
                          names: List[str], outs: List[Any], now: float,
                          exec_time_s: float,
-                         buckets: Optional[np.ndarray] = None) -> None:
+                         buckets: Optional[np.ndarray] = None) -> int:
         """Stage 4b: bulk-insert executed results into the reuse store + CS,
-        update TTC with the amortized per-request time, count executions.
+        update TTC with the amortized per-request time, count executions;
+        returns the embedding pages paged onto the device.
 
         Split from ``execute_batch`` so the async engine can defer the commit
         to the (virtual) completion event — and skip it entirely when a
@@ -149,7 +150,7 @@ class ReplicaEngine:
         # Page the fresh embeddings onto the device now, off the query
         # critical path: the next query_batch starts without an upload stall.
         # No-op until the store's kernel path has gone device-resident.
-        store.sync_device()
+        pages = store.sync_device()
         # amortized per-request time, matching the scalar path's batch-of-1
         # observations (maybe_backup compares a *single* request's elapsed
         # time against this EWMA)
@@ -157,6 +158,7 @@ class ReplicaEngine:
         for name, result in zip(names, outs):
             self.cs.insert(Data(name, content=result), now)
             self.stats.inc("executed")
+        return pages
 
     # ------------------------------------------------------------ sync paths
     def handle(self, req: ServeRequest, now: Optional[float] = None) -> Optional[ServeResult]:
