@@ -18,8 +18,12 @@ virtual-clock event loop (``core/sim_clock.py``):
   moment the leader's result exists (exact-name reuse at sim 1.0) and
   record their aggregation wait, instead of being re-handled.
 * **TTC-based straggler re-dispatch** — every executed group arms one
-  backup timer per task at ``BackupPolicy.backup_delay_s`` (factor x TTC,
-  paper §IV-C); a firing timer re-dispatches the task to the next replica,
+  backup timer per task at ``BackupPolicy.backup_delay_s`` (paper §IV-C):
+  factor x the expected time of the task's *group*, learned per replica,
+  service and group size (an EWMA of such groups' durations; an unseen size
+  falls back to rows x the per-row TTC).  The replicas' ``TTCEstimator``
+  stays per row: the network's TTC answers and the load gossip read it.
+  A firing timer re-dispatches the task to the next replica,
   whichever completion comes first wins the future (``try_set_result``),
   the loser's commit is skipped (no double insert), the winner back-fills
   the primary replica's Content Store, and ``BackupPolicy.cancel`` tears
@@ -141,6 +145,9 @@ class AsyncServingEngine:
         self.engine_stats = CounterGroup(
             {"backups": 0, "backup_wins": 0, "dispatches": 0,
              "exec_rows": 0, "discarded_rows": 0})
+        # (replica, service, rows) -> EWMA seconds of an executed group of
+        # that many rows: what a group's backup deadline is measured against
+        self._group_s: Dict[Tuple[int, str, int], float] = {}
 
     # --------------------------------------------------------------- submit
     def submit(self, req: ServeRequest) -> Future:
@@ -307,9 +314,9 @@ class AsyncServingEngine:
         # every cold start (e.g. a first-dispatch jit compile on the wall-
         # time path) into a spurious duplicate execution.
         if rep.ttc.informed(service):
-            ttc = rep.ttc.estimate(service)
+            expected = self._expected_group_s(exec_rid, service, len(missed))
             for task in missed:
-                delay = self.backup.backup_delay_s(ttc, task.backups_sent)
+                delay = self.backup.backup_delay_s(expected, task.backups_sent)
                 if (delay is not None
                         and len(task.dispatched) < len(self.replicas)):
                     timer = self.loop.at(t + delay, self._fire_backup, task)
@@ -323,6 +330,7 @@ class AsyncServingEngine:
         entirely — their results are discarded without touching the store or
         the CS, so a task is inserted exactly once fleet-wide."""
         t = self.loop.now
+        self._observe_group(exec_rid, service, len(tasks), duration)
         live = [(task, res) for task, res in zip(tasks, outs)
                 if not task.future.done]
         self.engine_stats.inc("discarded_rows", len(tasks) - len(live))
@@ -373,6 +381,27 @@ class AsyncServingEngine:
         return True
 
     # ------------------------------------------------------------ stragglers
+    def _expected_group_s(self, rid: int, service: str, rows: int) -> float:
+        """Expected seconds of a ``rows``-row group on replica ``rid``: the
+        EWMA of such groups' durations, else ``rows`` x the per-row TTC.
+
+        Per group size, because one call's fixed cost makes a small group
+        slower per row than a large one; the per-row TTC is amortized over
+        whatever mix of sizes ran."""
+        seen = self._group_s.get((rid, service, rows))
+        if seen is not None:
+            return seen
+        return rows * self.replicas[rid].ttc.estimate(service)
+
+    def _observe_group(self, rid: int, service: str, rows: int,
+                       duration: float) -> None:
+        """Fold a completed group's duration into its size's EWMA, with the
+        replica TTC's ``alpha``."""
+        key = (rid, service, rows)
+        alpha = self.replicas[rid].ttc.alpha
+        prev = self._group_s.get(key, duration)
+        self._group_s[key] = (1 - alpha) * prev + alpha * duration
+
     def _fire_backup(self, task: _Task) -> None:
         """TTC deadline exceeded: re-dispatch to the next untried replica."""
         if task.future.done:  # safety net; resolution cancels these timers

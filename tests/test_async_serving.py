@@ -344,6 +344,106 @@ class TestAsyncEngine:
         assert f2.done and f2.result.reuse == "cs" and f2.result.replica == 0
 
 
+    # ------------------------------------------- the group's backup deadline
+    @staticmethod
+    def _routed_group(eng, n, seed0):
+        """``n`` fresh embeddings the router sends to replica 0, and the
+        next unused seed."""
+        out, s = [], seed0
+        while len(out) < n:
+            v = _vecs(1, seed=s)[0]
+            s += 1
+            if eng.router.route(v)[0] == 0:
+                out.append(v)
+        return out, s
+
+    @staticmethod
+    def _serve_group(eng, vecs, first_id):
+        """Submit ``vecs`` at once (one flush, one group) and drain."""
+        futs = [eng.submit(ServeRequest(first_id + i, "svc", v))
+                for i, v in enumerate(vecs)]
+        eng.drain()
+        return futs
+
+    @pytest.mark.parametrize("second_s, backed_up", [(0.05, False),
+                                                     (0.2, True)])
+    def test_group_backs_up_past_its_learned_time(self, second_s, backed_up):
+        # the first two-row group teaches its size's time, 0.04 s; a later
+        # one is a straggler past 1.5 x that (0.06 s), although the per-row
+        # TTC would allow it 1.5 x 2 x 0.084 s
+        times = iter([0.04, second_s])
+        eng = AsyncServingEngine(
+            P, [ReplicaEngine(i, P, _execute) for i in range(2)],
+            backup=BackupPolicy(factor=1.5, max_backups=1), max_wait_s=0.005,
+            exec_time_fn=lambda rid, svc, reqs: (next(times) if rid == 0
+                                                 else 0.02))
+        self._prime_ttc(eng, t=0.1)
+        first, s = self._routed_group(eng, 2, 300)
+        self._serve_group(eng, first, 0)
+        assert eng.stats()["backups"] == 0
+        assert eng._expected_group_s(0, "svc", 2) == pytest.approx(0.04)
+        second, _ = self._routed_group(eng, 2, s)
+        futs = self._serve_group(eng, second, 2)
+        st = eng.stats()
+        assert all(f.result.backup == backed_up for f in futs)
+        if backed_up:
+            assert all(f.result.replica == 1 for f in futs)
+            assert st["backups"] == 2 and st["backup_wins"] == 2
+            assert (st["exec_rows"], st["discarded_rows"]) == (6, 2)
+        else:
+            assert st["backups"] == 0
+            assert (st["exec_rows"], st["discarded_rows"]) == (4, 0)
+        # the straggling group is learned too, its rows thrown away or not
+        assert eng._expected_group_s(0, "svc", 2) == pytest.approx(
+            0.8 * 0.04 + 0.2 * second_s)
+        assert eng.pending() == 0 and eng.backup.active() == 0
+
+    def test_one_row_group_after_full_groups_arms_no_backup(self):
+        # a call's fixed cost makes one row slower than an eighth of a full
+        # group; full groups pull the per-row TTC below two thirds of a
+        # one-row group's time, but a one-row group is held to its own
+        exec_s = lambda rid, svc, reqs: 0.015 + 0.017 * len(reqs)  # noqa: E731
+        eng = AsyncServingEngine(
+            P, [ReplicaEngine(i, P, _execute) for i in range(2)],
+            backup=BackupPolicy(factor=1.5, max_backups=1), max_wait_s=0.005,
+            max_batch=8, exec_time_fn=exec_s)
+        s, rid = 400, 0
+        for n in [1] * 3 + [8] * 10:
+            vecs, s = self._routed_group(eng, n, s)
+            self._serve_group(eng, vecs, rid)
+            rid += n
+        one = exec_s(0, "svc", [None])
+        # the old deadline, 1.5 x the per-row TTC, falls before it completes
+        assert 1.5 * eng.replicas[0].ttc.estimate("svc") < one
+        vecs, s = self._routed_group(eng, 1, s)
+        (fut,) = self._serve_group(eng, vecs, rid)
+        st = eng.stats()
+        assert not fut.result.backup and fut.result.replica == 0
+        assert st["backups"] == 0 and st["discarded_rows"] == 0
+        assert st["exec_rows"] == 3 + 80 + 1
+
+    @pytest.mark.parametrize("group_s, backups", [(0.13, 0), (0.14, 3)])
+    def test_unseen_group_size_falls_back_to_rows_times_ttc(self, group_s,
+                                                            backups):
+        # no three-row group has run: its deadline is 1.5 x 3 x 0.03 s
+        eng = AsyncServingEngine(
+            P, [ReplicaEngine(i, P, _execute) for i in range(2)],
+            backup=BackupPolicy(factor=1.5, max_backups=1), max_wait_s=0.005,
+            exec_time_fn=lambda rid, svc, reqs: (group_s if rid == 0
+                                                 else 0.03))
+        self._prime_ttc(eng, t=0.03)
+        assert eng._expected_group_s(0, "svc", 3) == pytest.approx(0.09)
+        vecs, _ = self._routed_group(eng, 3, 500)
+        futs = self._serve_group(eng, vecs, 0)
+        st = eng.stats()
+        assert st["backups"] == backups
+        # the primary finishes before any backup (0.135 + 0.03 s) can
+        assert all(f.result.replica == 0 and not f.result.backup
+                   for f in futs)
+        assert (st["exec_rows"], st["discarded_rows"]) == (3 + backups,
+                                                           backups)
+
+
 # --------------------------------------------------- sync facade + stages
 class TestSyncFacade:
     def test_submit_is_async_drained(self):
