@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
-Everything the run logged is held against the plain references
-(``reference/store.py`` for the router and the stores, ``reference/decoder.py``
-for the model), once the window has closed:
+Everything the run logged is held against the plain references, once the
+window has closed: ``reference/store.py`` for the router and the stores, and
+for the model the reference that the configuration file's ``reference`` key
+names (``reference/<reference>.py``, loaded by ``reference.load``):
 
 * ``route_wrong``   tasks whose buckets or replica the router got wrong;
 * ``search_wrong``  store answers (hit or miss, id, similarity, result) that
@@ -26,8 +27,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import generator
-from bench.reference import decoder
+from bench import generator, reference
 from bench.reference.store import TOL, LSHRef, StoreRef, normalize
 
 
@@ -194,7 +194,7 @@ def reference_logits(cfg: dict, weights, tokens: np.ndarray,
                      images: np.ndarray, image_pool, precision: str = "f32",
                      batch: int = 8) -> np.ndarray:
     """(n, V) last-position logits, computed in blocks of ``batch`` rows."""
-    fwd = decoder.last_logits(cfg["model"], precision)
+    fwd = reference.load(cfg["reference"]).last_logits(cfg["model"], precision)
     out = []
     for lo in range(0, len(tokens), batch):
         tok = np.zeros((batch, tokens.shape[1]), np.int32)

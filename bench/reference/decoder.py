@@ -1,5 +1,7 @@
-"""Plain reference of the decoder services: Qwen3 and Phi-3(-vision).
+"""Plain reference of dense global-attention decoders: Qwen3 and
+Phi-3(-vision), the configuration files whose ``reference`` is ``decoder``.
 
+It exports the model reference's contract (``bench/reference/__init__.py``).
 Straightforward ``jax.numpy`` in float32 at the highest matmul precision,
 written from the published model descriptions (Hugging Face ``modeling_qwen3``
 and ``modeling_phi3``), with no kernels, cache or batching tricks.  It imports
@@ -50,6 +52,43 @@ def shapes(model: dict) -> dict:
     if not model.get("tie_word_embeddings"):
         out["head"] = (V, d)
     return out
+
+
+def program_fields(m: dict):
+    """-> (set, expect): the program's ``ArchConfig`` fields to set from the
+    configuration's ``model`` block, and those it must already hold: a
+    dense decoder with global attention, silu MLP, no experts, no softcaps."""
+    set_ = {"n_layers": m["num_hidden_layers"], "norm_eps": m["rms_norm_eps"],
+            "rope_theta": float(m["rope_theta"]), "dtype": m["torch_dtype"]}
+    expect = {"d_model": m["hidden_size"], "d_ff": m["intermediate_size"],
+              "n_heads": m["num_attention_heads"],
+              "n_kv_heads": m["num_key_value_heads"],
+              "resolved_head_dim": m["head_dim"], "vocab_size": m["vocab_size"],
+              "tie_embeddings": m["tie_word_embeddings"],
+              "qk_norm": m["qk_norm"], "qkv_bias": m.get("attention_bias", False),
+              "n_frontend_tokens": m["frontend_tokens"], "mlp_act": "silu",
+              "layer_pattern": ("global",), "n_experts": 0,
+              "attn_logit_softcap": None, "final_logit_softcap": None,
+              "sliding_window": None, "scale_embeddings": False,
+              "use_post_norms": False}
+    return set_, expect
+
+
+def prefill_flops(model: dict, seq: int) -> float:
+    """FLOPs of one prompt's prefill through ``model`` (a configuration
+    file's ``model`` block) at ``seq`` positions, including image tokens."""
+    d = model["hidden_size"]
+    h = model["num_attention_heads"]
+    kv = model["num_key_value_heads"]
+    hd = model["head_dim"]
+    ff = model["intermediate_size"]
+    layers = model["num_hidden_layers"]
+    proj = 2 * seq * d * (h + 2 * kv) * hd + 2 * seq * h * hd * d
+    pairs = seq * (seq + 1) // 2                     # causal (q, k) pairs
+    attn = 2 * 2 * pairs * h * hd                    # scores and weighted sum
+    mlp = 2 * seq * d * 2 * ff + 2 * seq * ff * d    # gate+up, down
+    head = 2 * d * model["vocab_size"]               # last position's logits
+    return float(layers * (proj + attn + mlp) + head)
 
 
 def _is_shape(x) -> bool:

@@ -1,11 +1,15 @@
 """The served model behind the replicas, built through the program's API.
 
-``repro.configs.get_arch`` gives the architecture, cut in depth and set to
-the configuration file's numbers with ``dataclasses.replace``;
-``repro.models.build_model`` builds it, and its jitted ``prefill`` serves a
-replica's miss group as one call.  The weights are the benchmark's own
-(``reference.decoder.make_weights``), made on the device from the seed and
-checked against the layout of the program's ``init``.
+The configuration file's ``reference`` key names the model reference
+(``bench/reference/<reference>.py``, loaded by ``reference.load``; its
+contract is in ``bench/reference/__init__.py``).  ``repro.configs.get_arch``
+gives the architecture, set to the configuration file's numbers by the
+reference's ``program_fields`` with ``dataclasses.replace`` and checked
+against the fields it expects; ``repro.models.build_model`` builds it, and
+its jitted ``prefill`` serves a replica's miss group as one call.  The
+weights are the benchmark's own (the reference's ``make_weights``), made on
+the device from the seed and checked against the layout of the program's
+``init``.
 
 Rows of a group are padded to the next of the configuration's batch sizes;
 the function returns the argmax token of each real row.  How the program
@@ -22,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import decoder
+from bench import reference
 
 
 def arch_config(cfg: dict):
@@ -30,23 +34,10 @@ def arch_config(cfg: dict):
     the file's numbers."""
     from repro.configs import get_arch
 
-    m = cfg["model"]
-    arch = dataclasses.replace(
-        get_arch(cfg["arch"]), n_layers=m["num_hidden_layers"],
-        norm_eps=m["rms_norm_eps"], rope_theta=float(m["rope_theta"]),
-        dtype=m["torch_dtype"])
-    want = {"d_model": m["hidden_size"], "d_ff": m["intermediate_size"],
-            "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_key_value_heads"],
-            "resolved_head_dim": m["head_dim"], "vocab_size": m["vocab_size"],
-            "tie_embeddings": m["tie_word_embeddings"],
-            "qk_norm": m["qk_norm"], "qkv_bias": m.get("attention_bias", False),
-            "n_frontend_tokens": m["frontend_tokens"], "mlp_act": "silu",
-            "layer_pattern": ("global",), "n_experts": 0,
-            "attn_logit_softcap": None, "final_logit_softcap": None,
-            "sliding_window": None, "scale_embeddings": False,
-            "use_post_norms": False}
-    bad = {k: (getattr(arch, k), v) for k, v in want.items()
+    set_, expect = reference.load(cfg["reference"]).program_fields(
+        cfg["model"])
+    arch = dataclasses.replace(get_arch(cfg["arch"]), **set_)
+    bad = {k: (getattr(arch, k), v) for k, v in expect.items()
            if getattr(arch, k) != v}
     if bad:
         raise ValueError(f"program's {cfg['arch']} differs from the "
@@ -64,7 +55,8 @@ class Service:
         self.model_cfg = cfg["model"]
         self.arch = model_override or arch_config(cfg)
         self.model = build_model(self.arch)
-        self.weights = decoder.make_weights(self.model_cfg, key)
+        ref = reference.load(cfg["reference"])
+        self.weights = ref.make_weights(self.model_cfg, key)
         want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                            self.weights)

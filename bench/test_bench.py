@@ -10,15 +10,19 @@ import os
 import subprocess
 import sys
 import time
+import types
 
 import numpy as np
 import pytest
 
-from bench import flops, generator, harness
+from bench import flops, generator, harness, reference
 from bench import trace as tr
 from bench.reference import decoder
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIGS = sorted(f[:-len(".json")] for f in
+                 os.listdir(os.path.join(ROOT, "bench", "configs"))
+                 if f.endswith(".json"))
 
 
 def _config(name: str) -> dict:
@@ -32,7 +36,7 @@ def test_prefill_flops_qwen3_by_hand():
     # = 805,306,368; causal attention 4 * (32*33/2) * 16 * 128 = 4,325,376;
     # MLP 2*32*2048*(2*6144) + 2*32*6144*2048 = 2,415,919,104.  28 layers and
     # the last position's logits 2*2048*151936.
-    assert flops.prefill_flops(_config("qwen3-1.7b")["model"], 32) == \
+    assert decoder.prefill_flops(_config("qwen3-1.7b")["model"], 32) == \
         28 * (805_306_368 + 4_325_376 + 2_415_919_104) + 622_329_856
 
 
@@ -41,7 +45,7 @@ def test_prefill_flops_phi3v_by_hand():
     # + 2*608*32*96*3072 = 45,902,462,976; causal attention
     # 4 * (608*609/2) * 32 * 96 = 2,274,951,168; MLP 2*608*3072*(2*8192)
     # + 2*608*8192*3072 = 91,804,925,952.  16 layers and 2*3072*32064.
-    assert flops.prefill_flops(_config("phi3v-l16")["model"], 608) == \
+    assert decoder.prefill_flops(_config("phi3v-l16")["model"], 608) == \
         16 * (45_902_462_976 + 2_274_951_168 + 91_804_925_952) + 197_001_216
 
 
@@ -161,10 +165,11 @@ TINY = {
 
 
 def _tiny_run(which: str, fault=None, control=False, seed=2**31 + 11,
-              check=None):
+              check=None, ref=None):
     workload, cfg_name, arch_name, pool, traffic = TINY[which]
     cfg, arch = _tiny(cfg_name, arch_name, pool)
     cfg["check"].update(check or {})
+    cfg["reference"] = ref or cfg["reference"]
     stats: dict = {}
     out = harness.run(workload, seed, 1.5, False, t_start=time.perf_counter(),
                       require_tpu=False, fault=fault, config_override=cfg,
@@ -201,15 +206,99 @@ def test_float8_control_reads_wider_gaps_than_the_program():
     assert stats["program_gap"] <= out["checks"]["logit_gap"]["limit"]
 
 
-def test_reference_weight_layout_matches_the_program_init():
+@pytest.mark.parametrize("name", CONFIGS)
+def test_reference_weight_layout_matches_the_program_init(name):
     import jax
 
     from bench import service
 
-    cfg, arch = _tiny("phi3v-l16", "phi-3-vision-4.2b", 0)
+    cfg, arch = _tiny(name, _config(name)["arch"], 0)
+    ref = reference.load(cfg["reference"])
     svc = service.Service(cfg, jax.random.PRNGKey(0), model_override=arch)
-    assert set(svc.weights) == {"embed", "head", "final_norm", "layers_0"}
-    assert decoder.shapes(cfg["model"])["layers_0"]["attn"]["wq"] == (2, 64, 64)
+    want = ref.shapes(cfg["model"])
+    is_shape = lambda x: isinstance(x, tuple)   # noqa: E731
+    init = jax.eval_shape(svc.model.init, jax.random.PRNGKey(0))
+    for tree in (svc.weights, init):
+        assert jax.tree.structure(want, is_leaf=is_shape) == \
+            jax.tree.structure(tree)
+        assert jax.tree.leaves(want, is_leaf=is_shape) == \
+            [x.shape for x in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_program_arch_holds_the_fields_the_reference_expects(name):
+    from bench import service
+
+    cfg = _config(name)
+    set_, expect = reference.load(cfg["reference"]).program_fields(
+        cfg["model"])
+    arch = service.arch_config(cfg)
+    assert {k: getattr(arch, k) for k in {**set_, **expect}} == \
+        {**set_, **expect}
+
+
+@pytest.mark.parametrize("name,refused", [
+    ("no_such_model", "known: .*'decoder'"),   # no module of that name
+    ("store", "lacks \\['shapes'"),           # a module, but no model reference
+    ("../decoder", "known: .*'decoder'"),      # not a module name at all
+])
+def test_unknown_reference_is_refused_naming_the_known_ones(name, refused):
+    with pytest.raises(ValueError, match=refused):
+        reference.load(name)
+
+
+@pytest.fixture
+def planted(monkeypatch):
+    """A model reference added under a new name, with no edit to the
+    harness: ``bench.reference.planted`` wraps ``decoder``, except that its
+    ``last_logits`` puts the token after the true best first, by one logit,
+    and its ``prefill_flops`` counts double."""
+    def last_logits(model, precision="f32"):
+        fwd = decoder.last_logits(model, precision)
+
+        def favoured(w, tokens, images):
+            out = fwd(w, tokens, images)
+            other = (out.argmax(-1) + 1) % out.shape[-1]
+            rows = np.arange(out.shape[0])
+            return out.at[rows, other].set(out.max(-1) + 1.0)
+
+        return favoured
+
+    mod = types.ModuleType("bench.reference.planted")
+    mod.shapes = decoder.shapes
+    mod.make_weights = decoder.make_weights
+    mod.program_fields = decoder.program_fields
+    mod.last_logits = last_logits
+    mod.prefill_flops = lambda model, seq: 2 * decoder.prefill_flops(model, seq)
+    monkeypatch.setitem(sys.modules, mod.__name__, mod)
+    return mod
+
+
+def test_a_new_reference_is_built_served_and_checked(planted):
+    assert reference.load("planted") is planted
+    out, stats = _tiny_run("phi", ref="planted")
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert stats["served"].count(None) > 0
+    # the served tokens are the program's own, so only the logit gap fails:
+    # each lies one logit below the best that the planted reference names
+    assert out["correct"] is False
+    failing = {k for k, c in out["checks"].items() if c["value"] > c["limit"]}
+    assert failing == {"logit_gap"}
+    assert out["checks"]["logit_gap"]["value"] >= 1.0
+
+
+def test_prefill_mfu_counts_with_the_configured_reference(planted):
+    cfg = _config("phi3v-l16")
+    ctx = types.SimpleNamespace(
+        cfg=cfg, peaks={"bf16_flops": 197e12}, prompt_len=608,
+        spans=types.SimpleNamespace(calls={"execute": [0.05, 0.07, 0.06]}),
+        exec_calls=[(1, 1), (3, 4), (2, 2)])
+    sound = flops.prefill_mfu(ctx)
+    # 6 real rows of 2.24 TFLOP over 0.18 s at 197 TFLOP/s
+    assert sound == pytest.approx(100.0 * 6 * decoder.prefill_flops(
+        cfg["model"], 608) / (0.18 * 197e12))
+    ctx.cfg = dict(cfg, reference="planted")
+    assert flops.prefill_mfu(ctx) == pytest.approx(2 * sound)
 
 
 # ------------------------------------------------------------ the data
@@ -218,6 +307,11 @@ def test_benchmark_file_names_existing_files():
         bench = json.load(f)
     for c in bench["configs"]:
         assert os.path.exists(os.path.join(ROOT, c["file"]))
+        with open(os.path.join(ROOT, c["file"])) as f:
+            name = json.load(f)["reference"]
+        assert os.path.exists(os.path.join(ROOT, "bench", "reference",
+                                           name + ".py"))
+        reference.load(name)
     for w in bench["workloads"]:
         generator.load_traffic(w["traffic"])
     for m in bench["per_layer"]:
