@@ -4,8 +4,9 @@
 reference is a module of this package that a configuration file names under
 its ``reference`` key; the harness reaches it only through :func:`load`, so
 a new architecture enters the benchmark as a new module here and a new
-configuration file, with no edit to the harness.  Like ``decoder.py``, a
-model reference imports nothing of the program under test.  It exports:
+configuration file, with no edit to the harness or its tests.  Like
+``decoder.py``, a model reference imports nothing of the program under
+test.  It exports:
 
 ``shapes(model)``
     the weight layout: the leaf shapes of the program's ``init`` for a
@@ -23,7 +24,17 @@ model reference imports nothing of the program under test.  It exports:
 ``program_fields(model)``
     ``(set, expect)``, two plain dicts keyed by field names of the program's
     ``ArchConfig``: the service applies ``set`` with ``dataclasses.replace``
-    to ``get_arch(cfg["arch"])`` and then requires every key of ``expect``.
+    to ``get_arch(cfg["arch"])`` and then requires every key of ``expect``;
+``tiny(model)``
+    ``(model, fields)``: the ``model`` block at the size the CPU tests run,
+    every width this reference reads shrunk and the architecture's kinds of
+    layer kept in their ratio (at least one whole period of its pattern),
+    and the ``ArchConfig`` fields that ``dataclasses.replace`` sets on
+    ``get_arch(cfg["arch"])`` to make the program that model, in float32;
+    ``bench/service.py`` ``tiny_config`` applies them and holds the result
+    to ``program_fields`` of the tiny block.  So a new configuration file is
+    built, checked for layout and served at that size by the benchmark's
+    tests with no edit to them.
 """
 from __future__ import annotations
 
@@ -32,7 +43,7 @@ import pkgutil
 from types import ModuleType
 
 CONTRACT = ("shapes", "make_weights", "last_logits", "prefill_flops",
-            "program_fields")
+            "program_fields", "tiny")
 
 
 def load(name: str) -> ModuleType:

@@ -74,6 +74,23 @@ def program_fields(m: dict):
     return set_, expect
 
 
+def tiny(model: dict):
+    """-> (model, fields): ``model`` at the CPU tests' size, 2 layers of
+    hidden 64, 4 heads of 16, at most 2 kv heads, MLP 128, vocabulary 256
+    and 8 image tokens where it takes images, in float32; and the program's
+    ``ArchConfig`` fields that make the program that model."""
+    kv = model["num_key_value_heads"]
+    m = dict(model, num_hidden_layers=2, hidden_size=64, intermediate_size=128,
+             num_attention_heads=4, num_key_value_heads=min(kv, 2),
+             head_dim=16, vocab_size=256, torch_dtype="float32",
+             frontend_tokens=8 if model["frontend_tokens"] else 0)
+    set_, expect = program_fields(m)
+    widths = ("d_model", "d_ff", "n_heads", "n_kv_heads", "vocab_size",
+              "n_frontend_tokens")
+    return m, {**set_, **{k: expect[k] for k in widths},
+               "head_dim": m["head_dim"]}
+
+
 def prefill_flops(model: dict, seq: int) -> float:
     """FLOPs of one prompt's prefill through ``model`` (a configuration
     file's ``model`` block) at ``seq`` positions, including image tokens."""

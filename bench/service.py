@@ -19,6 +19,7 @@ called for a group needs a service executor inside the program first.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import List
 
@@ -34,14 +35,35 @@ def arch_config(cfg: dict):
     the file's numbers."""
     from repro.configs import get_arch
 
-    set_, expect = reference.load(cfg["reference"]).program_fields(
-        cfg["model"])
-    arch = dataclasses.replace(get_arch(cfg["arch"]), **set_)
-    bad = {k: (getattr(arch, k), v) for k, v in expect.items()
+    ref = reference.load(cfg["reference"])
+    set_, _ = ref.program_fields(cfg["model"])
+    return _held(dataclasses.replace(get_arch(cfg["arch"]), **set_), ref,
+                 cfg["model"], cfg["arch"])
+
+
+def tiny_config(cfg: dict):
+    """-> (cfg, arch): a configuration file at the size the CPU tests run,
+    as its reference's ``tiny`` gives it, and the program's ArchConfig for
+    it, checked against the tiny block's numbers as ``arch_config`` checks
+    the full size."""
+    from repro.configs import get_arch
+
+    ref = reference.load(cfg["reference"])
+    model, fields = ref.tiny(cfg["model"])
+    arch = dataclasses.replace(get_arch(cfg["arch"]), **fields)
+    return ({**copy.deepcopy(cfg), "model": model},
+            _held(arch, ref, model, cfg["arch"]))
+
+
+def _held(arch, ref, model: dict, name: str):
+    """``arch`` if it holds every field that ``ref.program_fields(model)``
+    sets or expects, else a ``ValueError`` naming those it differs in."""
+    set_, expect = ref.program_fields(model)
+    bad = {k: (getattr(arch, k), v) for k, v in {**set_, **expect}.items()
            if getattr(arch, k) != v}
     if bad:
-        raise ValueError(f"program's {cfg['arch']} differs from the "
-                         f"configuration file: {bad}")
+        raise ValueError(f"program's {name} differs from the model "
+                         f"block it is built for: {bad}")
     return arch
 
 

@@ -135,47 +135,50 @@ def test_measurement_path_refuses_the_cpu(tmp_path):
 
 
 # ------------------------------------------------------- the check, tiny
-def _tiny(cfg_name: str, arch_name: str, pool: int):
-    from repro.configs import get_arch
-
-    cfg = _config(cfg_name)
-    arch = get_arch(arch_name).reduced()
-    cfg["model"].update({
-        "num_hidden_layers": arch.n_layers, "hidden_size": arch.d_model,
-        "intermediate_size": arch.d_ff, "num_attention_heads": arch.n_heads,
-        "num_key_value_heads": arch.n_kv_heads,
-        "head_dim": arch.resolved_head_dim, "vocab_size": arch.vocab_size,
-        "rms_norm_eps": arch.norm_eps, "rope_theta": arch.rope_theta,
-        "frontend_tokens": arch.n_frontend_tokens})
-    cfg["service"]["image_pool"] = pool
-    cfg["check"].update(executed_sample=16, query_sample=64)
-    return cfg, arch
-
-
+CELL = "phi3v-l16.pandaset.over"
+# execution-heavy: i.i.d. frames (with images where the model takes them),
+# no history
+EXECUTION = {"stream": "pandaset", "threshold": 0.9, "rate_per_s": 60.0,
+             "history": 0, "warmup_tasks": 10, "drain_s": 60.0}
 TINY = {
     # reuse-heavy: a CCTV stream over a small history
-    "qwen": ("phi3v-l16.pandaset.over", "qwen3-1.7b", "qwen3-1.7b", 0,
+    "qwen": ("qwen3-1.7b",
              {"stream": "cctv1", "threshold": 0.9, "rate_per_s": 60.0,
               "history": 600, "warmup_tasks": 10, "drain_s": 60.0}),
-    # execution-heavy: i.i.d. frames with images, no history
-    "phi": ("phi3v-l16.pandaset.over", "phi3v-l16", "phi-3-vision-4.2b", 4,
-            {"stream": "pandaset", "threshold": 0.9, "rate_per_s": 60.0,
-             "history": 0, "warmup_tasks": 10, "drain_s": 60.0}),
+    "phi": ("phi3v-l16", EXECUTION),
 }
 
 
-def _tiny_run(which: str, fault=None, control=False, seed=2**31 + 11,
-              check=None, ref=None):
-    workload, cfg_name, arch_name, pool, traffic = TINY[which]
-    cfg, arch = _tiny(cfg_name, arch_name, pool)
-    cfg["check"].update(check or {})
-    cfg["reference"] = ref or cfg["reference"]
+def _tiny(cfg: dict):
+    """A configuration at its reference's tiny size, with a pool of 4 images
+    where the tiny model takes images.  The check compares every executed
+    task, so that a fault planted in a few groups cannot hide outside a
+    sample."""
+    from bench import service
+
+    cfg, arch = service.tiny_config(cfg)
+    cfg["service"]["image_pool"] = 4 if cfg["model"]["frontend_tokens"] else 0
+    cfg["check"].update(executed_sample=1 << 20, query_sample=64)
+    return cfg, arch
+
+
+def _serve(cfg: dict, arch, traffic: dict, fault=None, control=False,
+           seed=2**31 + 11):
+    """One run of the harness on the CPU, 1.5 s of ``traffic``."""
     stats: dict = {}
-    out = harness.run(workload, seed, 1.5, False, t_start=time.perf_counter(),
+    out = harness.run(CELL, seed, 1.5, False, t_start=time.perf_counter(),
                       require_tpu=False, fault=fault, config_override=cfg,
                       traffic_override=traffic, model_override=arch,
                       stats=stats, control=control)
     return out, stats
+
+
+def _tiny_run(which: str, fault=None, control=False, check=None, ref=None):
+    cfg_name, traffic = TINY[which]
+    cfg, arch = _tiny(_config(cfg_name))
+    cfg["check"].update(check or {})
+    cfg["reference"] = ref or cfg["reference"]
+    return _serve(cfg, arch, traffic, fault, control)
 
 
 @pytest.mark.parametrize("which,fault", [
@@ -206,13 +209,11 @@ def test_float8_control_reads_wider_gaps_than_the_program():
     assert stats["program_gap"] <= out["checks"]["logit_gap"]["limit"]
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_reference_weight_layout_matches_the_program_init(name):
+def _holds_the_program_layout(cfg: dict, arch) -> None:
     import jax
 
     from bench import service
 
-    cfg, arch = _tiny(name, _config(name)["arch"], 0)
     ref = reference.load(cfg["reference"])
     svc = service.Service(cfg, jax.random.PRNGKey(0), model_override=arch)
     want = ref.shapes(cfg["model"])
@@ -223,6 +224,47 @@ def test_reference_weight_layout_matches_the_program_init(name):
             jax.tree.structure(tree)
         assert jax.tree.leaves(want, is_leaf=is_shape) == \
             [x.shape for x in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_reference_weight_layout_matches_the_program_init(name):
+    _holds_the_program_layout(*_tiny(_config(name)))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_every_configuration_serves_correctly_at_tiny_size(name):
+    """Each configuration file at its reference's tiny size, served through
+    the harness on execution-heavy traffic and held to its reference."""
+    out, stats = _serve(*_tiny(_config(name)), EXECUTION, seed=2**31 + 23)
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert stats["served"].count(None) > 0
+
+
+TINY_FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
+               "d_ff", "vocab_size", "n_frontend_tokens", "norm_eps",
+               "rope_theta", "dtype")
+
+
+@pytest.mark.parametrize("name,numbers", [
+    # the numbers ArchConfig.reduced() gave these tiny runs before their
+    # references sized them, but phi3v's eps: its block's 1e-5, where
+    # reduced() kept the registry's 1e-6
+    ("phi3v-l16", (2, 64, 4, 2, 16, 128, 256, 8, 1e-5, 1e4, "float32")),
+    ("qwen3-1.7b", (2, 64, 4, 2, 16, 128, 256, 0, 1e-6, 1e6, "float32")),
+])
+def test_decoder_tiny_keeps_the_size_the_tiny_runs_had(name, numbers):
+    from bench import service
+
+    full = _config(name)
+    cfg, arch = service.tiny_config(full)
+    assert tuple(getattr(arch, k) for k in TINY_FIELDS) == numbers
+    m = cfg["model"]
+    assert (m["num_hidden_layers"], m["hidden_size"], m["num_attention_heads"],
+            m["num_key_value_heads"], m["head_dim"], m["intermediate_size"],
+            m["vocab_size"], m["frontend_tokens"], m["rms_norm_eps"],
+            m["rope_theta"], m["torch_dtype"]) == numbers
+    assert full == _config(name)   # the configuration given is left as it was
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -268,6 +310,7 @@ def planted(monkeypatch):
     mod.shapes = decoder.shapes
     mod.make_weights = decoder.make_weights
     mod.program_fields = decoder.program_fields
+    mod.tiny = decoder.tiny
     mod.last_logits = last_logits
     mod.prefill_flops = lambda model, seq: 2 * decoder.prefill_flops(model, seq)
     monkeypatch.setitem(sys.modules, mod.__name__, mod)
@@ -299,6 +342,51 @@ def test_prefill_mfu_counts_with_the_configured_reference(planted):
         cfg["model"], 608) / (0.18 * 197e12))
     ctx.cfg = dict(cfg, reference="planted")
     assert flops.prefill_mfu(ctx) == pytest.approx(2 * sound)
+
+
+@pytest.fixture
+def shrunk(monkeypatch):
+    """Two model references added under new names, each ``decoder`` but for
+    its ``tiny``: ``bench.reference.shrunk`` sizes the tiny model its own
+    way (3 layers of hidden 32, 2 heads of 16, 1 kv head, MLP 96,
+    vocabulary 128), ``bench.reference.contrary`` names 4 layers in its
+    fields against the 3 of its block."""
+    def tiny(model):
+        m = dict(model, num_hidden_layers=3, hidden_size=32,
+                 intermediate_size=96, num_attention_heads=2,
+                 num_key_value_heads=1, head_dim=16, vocab_size=128,
+                 frontend_tokens=0, torch_dtype="float32")
+        set_, _ = decoder.program_fields(m)
+        return m, {**set_, "d_model": 32, "d_ff": 96, "n_heads": 2,
+                   "n_kv_heads": 1, "head_dim": 16, "vocab_size": 128,
+                   "n_frontend_tokens": 0}
+
+    def contrary(model):
+        m, fields = tiny(model)
+        return m, {**fields, "n_layers": 4}
+
+    for name, fn in (("shrunk", tiny), ("contrary", contrary)):
+        mod = types.ModuleType("bench.reference." + name)
+        for f in reference.CONTRACT:
+            setattr(mod, f, getattr(decoder, f))
+        mod.tiny = fn
+        monkeypatch.setitem(sys.modules, mod.__name__, mod)
+
+
+def test_a_new_reference_sizes_its_own_tiny_model(shrunk):
+    # the program follows the reference's numbers, not a preset of its own
+    cfg, arch = _tiny(dict(_config("qwen3-1.7b"), reference="shrunk"))
+    assert (arch.n_layers, arch.d_model, arch.n_heads, arch.n_kv_heads,
+            arch.resolved_head_dim, arch.d_ff, arch.vocab_size) == \
+        (3, 32, 2, 1, 16, 96, 128)
+    _holds_the_program_layout(cfg, arch)
+
+
+def test_tiny_fields_that_contradict_the_tiny_block_are_refused(shrunk):
+    from bench import service
+
+    with pytest.raises(ValueError, match="n_layers"):
+        service.tiny_config(dict(_config("qwen3-1.7b"), reference="contrary"))
 
 
 # ------------------------------------------------------------ the data

@@ -12,7 +12,7 @@ import pytest
 from bench import generator, harness
 from bench import program_trace as pt
 from bench import trace as tr
-from bench.test_bench import TINY, _tiny
+from bench.test_bench import TINY, _config, _tiny
 
 MS = 1e6
 NEW_READERS = ("queue_wait_ms_per_task", "wasted_exec_rows_pct",
@@ -137,8 +137,8 @@ def tiny_run():
 
     from bench import service
 
-    _, cfg_name, arch_name, pool, traffic = TINY["phi"]
-    cfg, arch = _tiny(cfg_name, arch_name, pool)
+    cfg_name, traffic = TINY["phi"]
+    cfg, arch = _tiny(_config(cfg_name))
     svc = service.Service(cfg, jax.random.PRNGKey(0), model_override=arch)
     svc.warm()
     engine = harness.build_engine(cfg, svc)
